@@ -45,7 +45,7 @@ from repro.core.engine import Engine, WindowSpec
 from repro.core.events import Event
 from repro.core.partition import PartitionedEngine
 from repro.data.streams import StreamSpec, random_stream
-from repro.kernels.ops import cer_pipeline as ops_cer_pipeline
+from repro.kernels import ops
 from repro.vector import (PartitionedStreamingEngine, StreamingVectorEngine,
                           VectorEngine)
 from repro.vector.multiquery import MultiQueryEngine
@@ -83,7 +83,7 @@ def compare_fused(num_events: int = 4096, batch: int = 16, epsilon: int = 95,
     Baseline mirrors the seed VectorEngine.run: eager bit-vector evaluation,
     eager class gather, then the jitted scan — three dispatches and two
     (T·B)-sized intermediates.  Optimized is ONE jitted call of
-    ops.cer_pipeline(impl="fused").
+    ops.cer_pipeline on the fused route.
 
     Both paths run CHUNKED at ``chunk`` events — the streaming regime where
     the engine actually operates.  Fusion's win is per-dispatch overhead +
@@ -220,11 +220,15 @@ def fused_tile_sweep(num_events: int = 4096, batch: int = 16,
 
 def _tile_call(ve, attrs, state, *, epsilon, b_tile, t_tile, use_pallas):
     t = ve.tables
-    return ops_cer_pipeline(
+    T, B, A = attrs.shape
+    route = ops.plan_pipeline(
+        T=T, B=B, A=A, W=state.shape[1], S=t.num_states,
+        NC=t.num_classes, NQ=1, V=t.class_ind.shape[0],
+        use_pallas=use_pallas, b_tile=b_tile, t_tile=t_tile)
+    return ops.cer_pipeline(
         attrs, ve.encoder.specs, t.class_of, t.class_ind, t.m_all,
         t.finals[None, :], state, init_mask=t.init_mask, epsilon=epsilon,
-        start_pos=0, impl="fused", use_pallas=use_pallas, b_tile=b_tile,
-        t_tile=t_tile)[0]
+        start_pos=0, route=route)[0]
 
 
 def streaming_throughput(total_events: int = 8192, batch: int = 16,

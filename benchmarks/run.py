@@ -9,9 +9,13 @@ events/second).
 ``--cer-json PATH`` runs ONLY the CER perf trajectory (fused vs unfused vs
 packed multi-query, events/sec + compile counts) and writes a JSON record so
 future PRs can diff perf against this one — see scripts/check.sh.
+
+The persistent compile cache follows ``JAX_COMPILATION_CACHE_DIR`` when set,
+else ``<checkout>/.jax_cache``.
 """
 import argparse
 import json
+import os
 import sys
 
 
@@ -133,6 +137,9 @@ def main() -> None:
                     help="write the CER perf trajectory record to PATH and "
                          "skip the paper-figure sweeps")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
 
     if args.cer_json:
         rec = cer_trajectory(quick=args.quick, events=args.events)

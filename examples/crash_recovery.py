@@ -4,11 +4,12 @@ set is bit-identical to an uninterrupted run (DESIGN.md §10).
 
     PYTHONPATH=src python examples/crash_recovery.py
 
-Three runs of the same deterministic PARTITION BY workload (NULL keys and
-missing attrs included, tECS arena on):
+Three worker subprocesses run the same deterministic PARTITION BY workload
+(NULL keys and missing attrs included, tECS arena on); the parent never
+initializes JAX, so on an accelerator each worker gets the device in turn:
 
-1. an in-process *oracle* run that never crashes;
-2. a worker subprocess that checkpoints every 4 chunks and SIGKILLs itself
+1. an *oracle* worker that never crashes;
+2. a worker that checkpoints every 4 chunks and SIGKILLs itself
    mid-interval (after chunk 11: checkpoints at 4 and 8, emission log
    through 10 — the checkpoint is deliberately BEHIND the log);
 3. the same worker restarted: it resumes from the newest checkpoint,
@@ -75,20 +76,19 @@ def main() -> None:
         run_worker(args.worker, args.crash_after)
         return
 
-    from repro.runtime import RecoveringStreamRunner, cumulative_matches
-    stream = make_stream()
-    chunks = [stream[lo:lo + CHUNK] for lo in range(0, TOTAL, CHUNK)]
+    # reads the emission logs only: importing it initializes no JAX backend
+    from repro.runtime.recovery import cumulative_matches
+    worker = [sys.executable, os.path.abspath(__file__), "--worker"]
     with tempfile.TemporaryDirectory() as tmp:
         d_ref = os.path.join(tmp, "uninterrupted")
-        runner = RecoveringStreamRunner(make_engine(), d_ref, every=EVERY)
-        for ch in chunks:
-            runner.process(ch)
-        runner.close()
+        p = subprocess.run(worker + [d_ref])
+        if p.returncode != 0:
+            sys.exit(f"oracle worker failed: rc={p.returncode}")
         oracle = cumulative_matches(d_ref)
         assert oracle["hits"], "workload produced no matches"
 
         d = os.path.join(tmp, "crashed")
-        cmd = [sys.executable, os.path.abspath(__file__), "--worker", d]
+        cmd = worker + [d]
         p = subprocess.run(cmd + ["--crash-after", str(CRASH_AFTER)])
         if p.returncode != -signal.SIGKILL:
             sys.exit(f"expected the worker to die by SIGKILL, "

@@ -11,15 +11,9 @@ set -euo pipefail
 cd "$(dirname "$0")/.."
 export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
 
-# Run the whole suite ONCE, under a fixed hypothesis seed when hypothesis is
-# available (the property-based arena parity suite in test_tecs_arena.py /
-# test_paper_claims.py must be deterministic in CI; without hypothesis the
-# @given tests skip via tests/_hyp.py and the flag would be unknown).
-HYP_ARGS=()
-if python -c "import hypothesis" 2>/dev/null; then
-    HYP_ARGS=(--hypothesis-seed=0)
-fi
-python -m pytest -q ${HYP_ARGS[@]+"${HYP_ARGS[@]}"}
+# Run the whole suite ONCE, under a fixed hypothesis seed (the
+# property-based arena parity suites must be deterministic in CI).
+python -m pytest -q --hypothesis-seed=0
 
 if [[ "${1:-}" != "--no-bench" ]]; then
     # quickstart doubles as the examples smoke step: it asserts host ≡

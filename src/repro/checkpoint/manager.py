@@ -26,11 +26,10 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import numpy as np
 
-from ..jaxcompat import tree_flatten_with_path
 
 
 def _flatten_with_paths(tree: Any) -> Tuple[List[Tuple[str, Any]], Any]:
-    flat, treedef = tree_flatten_with_path(tree)
+    flat, treedef = jax.tree.flatten_with_path(tree)
     out = []
     for path, leaf in flat:
         key = "/".join(str(getattr(p, "key", getattr(p, "idx", p)))

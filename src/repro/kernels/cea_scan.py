@@ -26,11 +26,10 @@ Layout / schedule
   forced a fresh compile per chunk offset.)
 * Windows: these legacy kernels implement the count-window (events)
   eviction rule only; time windows (DESIGN.md §9) route through the fused
-  kernel / fused-XLA path, which consume the generalized
-  :func:`_ring_masks_time` mask defined here.
+  kernel / fused-XLA path.
 
 VMEM budget per tile: C-scratch ``B_tile·W·S·4`` + ``M_all C·S·S·4`` +
-blocks; ops.py checks it against ~16 MB before launching.
+blocks; ops.py checks it against the chip's scoped limit before launching.
 """
 from __future__ import annotations
 
@@ -40,6 +39,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+#: run counts are exact integers below 2^24; the TPU's default f32 matmul
+#: rounds operands to bfloat16, so every count contraction asks for full
+#: precision (a no-op on CPU)
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _vmem_scratch(shape):
@@ -60,48 +64,6 @@ def _ring_masks(j, W: int, epsilon: int):
     return seed_mask, jnp.maximum(seed_mask, expire)
 
 
-def _ring_masks_lanes(j, W: int, epsilon: int):
-    """Per-lane ring masks: ``j`` is a (B_tile,) int32 vector of positions.
-
-    PARTITION BY lanes sit at independent substream offsets (DESIGN.md §6),
-    so seed/expire slots differ per lane.  Returns ``(seed_mask, clear)``,
-    both (B_tile, W) f32 0/1 masks.
-    """
-    arange_w = jax.lax.iota(jnp.int32, W)
-    seed_mask = (arange_w[None, :] == (j % W)[:, None]).astype(jnp.float32)
-    expire = (arange_w[None, :]
-              == ((j - epsilon - 1) % W)[:, None]).astype(jnp.float32)
-    return seed_mask, jnp.maximum(seed_mask, expire)
-
-
-def _ring_masks_time(j, ts_t, ts_ring, W: int, size):
-    """Per-lane *time-window* ring masks (DESIGN.md §9).
-
-    The generalization of :func:`_ring_masks_lanes`: instead of evicting
-    exactly the one start that left a count window, every slot whose start
-    timestamp ``ts_ring[b, w]`` fell below ``ts_t[b] - size`` masks to zero
-    (several may expire at once under non-uniform gaps; never-seeded slots
-    carry ``-inf`` and always read expired).  Count windows are the
-    degenerate case ``ts ≡ position, size = ε`` — this mask then equals the
-    classic rule, which the count path keeps for its closed-form one-hot.
-
-    j: (B_tile,) int32 positions (seeding stays position-driven);
-    ts_t: (B_tile,) f32 event timestamps; ts_ring: (B_tile, W) f32.
-    Returns ``(seed_mask, clear, seed_b, overflow)`` — seed/clear as f32
-    0/1 masks, ``seed_b`` the bool seed mask (for the timestamp-ring
-    update), ``overflow`` (B_tile,) bool: the seed slot's previous start
-    was still inside the window, i.e. more than W starts are
-    simultaneously live (the rate bound; latched by the caller).
-    """
-    arange_w = jax.lax.iota(jnp.int32, W)
-    seed_b = arange_w[None, :] == (j % W)[:, None]          # (B_tile, W)
-    expire_b = ts_ring < ts_t[:, None] - size
-    overflow = jnp.any(seed_b & ~expire_b, axis=1)
-    seed_mask = seed_b.astype(jnp.float32)
-    clear = jnp.maximum(seed_mask, expire_b.astype(jnp.float32))
-    return seed_mask, clear, seed_b, overflow
-
-
 def latest_slot_counts(C2, fq, j, latest_q):
     """Per-query counts with LAST queries reduced to the latest live seed slot.
 
@@ -114,12 +76,14 @@ def latest_slot_counts(C2, fq, j, latest_q):
     Returns m: (B, Q) f32.
     """
     W = C2.shape[1]
-    mw = jnp.einsum("bws,qs->bwq", C2, fq)                     # (B, W, Q)
+    mw = jnp.einsum("bws,qs->bwq", C2, fq,
+                    precision=_HIGHEST)                         # (B, W, Q)
     arange_w = jax.lax.iota(jnp.int32, W)
     age = (j[:, None] - arange_w[None, :]) % W                  # (B, W)
     posm = (mw > 0).astype(C2.dtype)
     younger = (age[:, :, None] < age[:, None, :]).astype(C2.dtype)
-    blocked = jnp.einsum("bvw,bvq->bwq", younger, posm)         # (B, W, Q)
+    blocked = jnp.einsum("bvw,bvq->bwq", younger, posm,
+                         precision=_HIGHEST)                    # (B, W, Q)
     keep = posm * (1.0 - jnp.minimum(blocked, 1.0))
     m_latest = jnp.sum(mw * keep, axis=1)                       # (B, Q)
     m_all = jnp.sum(mw, axis=1)
@@ -141,7 +105,8 @@ def consume_clear(C2, m, consume_sq):
     """
     trig = (m > 0).astype(C2.dtype)                             # (B, Q)
     clear_s = jnp.minimum(
-        jnp.einsum("bq,qs->bs", trig, consume_sq.astype(C2.dtype)), 1.0)
+        jnp.einsum("bq,qs->bs", trig, consume_sq.astype(C2.dtype),
+                   precision=_HIGHEST), 1.0)
     return C2 * (1.0 - clear_s)[:, None, :]
 
 

@@ -1,8 +1,10 @@
 """jit'd public wrappers around the Pallas kernels.
 
 Handles: TPU-alignment padding (S → ×128 MXU lanes, W → ×8 f32 sublanes,
-B → ×b_tile), interpret-mode fallback off-TPU, VMEM budget checks, and
-re-slicing outputs back to logical shapes.  The pure-jnp oracles live in
+B → ×b_tile), the kernel-or-XLA route (:func:`plan_pipeline`, recorded by
+the engines as a :class:`Route`), and re-slicing outputs back to logical
+shapes.  Pallas kernels run compiled on a TPU and in interpret mode only on
+the CPU backend or when the caller asks for it.  The pure-jnp oracles live in
 :mod:`repro.kernels.ref`; tests assert allclose between the two on shape /
 dtype sweeps.
 
@@ -10,10 +12,11 @@ Pipeline routing (DESIGN.md §3/§5): :func:`cer_pipeline` is the single entry
 point for the device CER pipeline and routes between
 
 * ``impl="fused"``   — ONE dispatch: the fused Pallas kernel
-  (:mod:`repro.kernels.fused_scan`), or, when Pallas is unavailable /
-  misaligned, one fused XLA computation (callers jit it as a unit, so the
-  ``bits``/``class_ids`` intermediates never round-trip through host or
-  dispatch boundaries).
+  (:mod:`repro.kernels.fused_scan`), or, when the shapes do not fit it
+  (ring not ×8, VMEM estimate over the chip's scoped limit), one fused XLA
+  computation (callers jit it as a unit, so the ``bits``/``class_ids``
+  intermediates never round-trip through host or dispatch boundaries).
+  Which of the two, and why, is the :class:`Route` the engines record.
 * ``impl="unfused"`` — the legacy three-dispatch path (bit-vector kernel →
   class gather → CEA scan kernel), kept as a perf baseline and oracle.
 * ``impl="ref"``     — pure-jnp oracles end to end.
@@ -28,6 +31,7 @@ carry the ``{"C", "ts", "ovf"}`` state pytree through the same signatures.
 """
 from __future__ import annotations
 
+from dataclasses import dataclass
 from typing import Optional, Sequence, Tuple, Union
 
 import jax
@@ -35,19 +39,81 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import ref
-from .arena_update import arena_update_pallas
 from .bitvector import bitvector_pallas
 from .cea_scan import cea_scan_multi_pallas, cea_scan_pallas
 from .fused_scan import DEFAULT_T_TILE, fused_scan_pallas
 from .window import TS_EMPTY, DeviceWindow
 
-VMEM_BYTES = 16 * 1024 * 1024  # v5e VMEM per core (we budget ~16 MB)
+#: Scoped VMEM a Pallas kernel may use without raising the compiler's
+#: limit, keyed by ``jax.Device.device_kind`` (JAX Pallas TPU docs: 16 MiB
+#: default scoped VMEM on v5e, of 128 MiB physical).  A TPU kind missing
+#: here is an error — add its row — never a guessed default.
+VMEM_LIMIT_BYTES = {"TPU v5 lite": 16 * 1024 * 1024}
+
+#: The chip whose limits the CPU interpreter (and a compile for a described
+#: chip) routes by, so interpret-mode runs take the kernel/XLA decisions a
+#: deployment on that chip would.
+TARGET_KIND = "TPU v5 lite"
 
 IMPLS = ("fused", "unfused", "ref")
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+@dataclass(frozen=True)
+class Route:
+    """The path one compiled stage takes, and why.
+
+    ``path`` is ``"pallas"`` (the fused kernel), ``"unfused"`` (the legacy
+    three-dispatch kernels) or ``"xla"``.  Engines decide it from shapes at
+    construction and keep it per stage (``engine.routes``), so no switch
+    from kernel to XLA or to interpret mode goes unrecorded.
+    """
+
+    path: str
+    reason: str
+    interpret: bool = False
+    b_tile: int = 8
+    t_tile: int = 1
+
+    def describe(self) -> str:
+        mode = " (interpret mode)" if self.interpret else ""
+        return f"{self.path}{mode}: {self.reason}"
+
+
+#: the pure-jnp oracle's route (``impl="ref"``)
+REF_ROUTE = Route("xla", "impl='ref': the caller asked for the pure-jnp "
+                         "oracle")
+
+#: the arena stage's route: the block builder has no kernel
+ARENA_ROUTE = Route("xla", "the tECS block builder is one XLA computation "
+                           "on every platform (no Pallas kernel)")
+
+
+def vmem_limit(device_kind: str) -> int:
+    """Scoped VMEM budget of a TPU kind (:data:`VMEM_LIMIT_BYTES`)."""
+    try:
+        return VMEM_LIMIT_BYTES[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no VMEM budget is known for TPU kind {device_kind!r}; add it "
+            "to repro.kernels.ops.VMEM_LIMIT_BYTES") from None
+
+
+def _default_interpret(interpret: Optional[bool]) -> bool:
+    """Interpret mode on the CPU backend only, unless the caller says."""
+    if interpret is not None:
+        return interpret
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise ValueError(f"Pallas TPU kernels cannot run on {backend!r}; "
+                         "pass use_pallas=False")
+    return backend == "cpu"
+
+
+def _target_kind() -> str:
+    """Device kind whose limits apply: the attached TPU's, else the
+    target chip's (interpret mode, or a compile for a described chip)."""
+    dev = jax.devices()[0]
+    return dev.device_kind if dev.platform == "tpu" else TARGET_KIND
 
 
 def _pad_to(x: int, m: int) -> int:
@@ -62,11 +128,6 @@ def ring_size(epsilon: int) -> int:
 def _start_arr(start_pos: Union[int, jnp.ndarray]) -> jnp.ndarray:
     """Dynamic start position → (1,) int32 SMEM operand (never a static)."""
     return jnp.reshape(jnp.asarray(start_pos, jnp.int32), (1,))
-
-
-def _is_lane_vector(start_pos) -> bool:
-    """True when start_pos is a per-lane (B,) vector rather than a scalar."""
-    return getattr(start_pos, "ndim", 0) >= 1
 
 
 def _lane_arr(x, B: int, pad_to: int, fill: int) -> jnp.ndarray:
@@ -108,7 +169,7 @@ def bitvector(attrs: jnp.ndarray, specs: Sequence[Tuple[int, int, float]],
         ops = jnp.asarray([s[1] for s in specs], dtype=jnp.int32)
         thr = jnp.asarray([s[2] for s in specs], dtype=jnp.float32)
         return ref.bitvector_ref(attrs, idx, ops, thr)
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = _default_interpret(interpret)
     B, A = attrs.shape
     b_tile = min(256, _pad_to(B, 8))
     Bp = _pad_to(B, b_tile)
@@ -149,13 +210,14 @@ def cea_scan(class_ids: jnp.ndarray, m_all: jnp.ndarray, finals: jnp.ndarray,
         return _scan_xla(class_ids, m_all, finals, c0, epsilon, start_pos,
                          init_state)
 
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = _default_interpret(interpret)
     if W % 8 != 0:
         # Ring arithmetic is mod W, so W cannot be padded here without
         # stranding carried-over starts: the caller must allocate the ring at
-        # ring_size(epsilon) (×8).  Fall back to the exact XLA path otherwise.
-        return _scan_xla(class_ids, m_all, finals, c0, epsilon, start_pos,
-                         init_state)
+        # ring_size(epsilon) (×8), or ask for the XLA path.
+        raise ValueError(f"the scan kernel needs a ring of ×8 slots, got "
+                         f"W={W}; allocate ring_size(epsilon) or pass "
+                         "use_pallas=False")
     # --- TPU alignment padding ---------------------------------------------
     Sp = _pad_to(S, 128)
     Bp = _pad_to(B, b_tile)
@@ -166,7 +228,7 @@ def cea_scan(class_ids: jnp.ndarray, m_all: jnp.ndarray, finals: jnp.ndarray,
     ids_pad = jnp.pad(class_ids.T, ((0, Bp - B), (0, 0)))  # (Bp, T)
 
     vmem = 4 * (b_tile * W * Sp * 2 + NCp * Sp * Sp + b_tile * W * Sp)
-    if vmem > VMEM_BYTES:
+    if vmem > vmem_limit(_target_kind()):
         raise ValueError(f"cea_scan VMEM budget exceeded: {vmem} bytes "
                          f"(W={W}, S={Sp}, C={NCp}, b_tile={b_tile})")
 
@@ -200,12 +262,15 @@ def cea_scan_multi(class_ids: jnp.ndarray, m_all: jnp.ndarray,
     NC, S, _ = m_all.shape
     NQ = finals_q.shape[0]
     W = c0.shape[1]
-    if not use_pallas or W % 8 != 0:
+    if not use_pallas:
         c_fin, m = ref.cea_scan_multi_ref(c0, m_all, class_ids, finals_q,
                                           init_mask, epsilon,
                                           start_pos=start_pos)
         return m, c_fin
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    if W % 8 != 0:
+        raise ValueError(f"the scan kernel needs a ring of ×8 slots, got "
+                         f"W={W}; pass use_pallas=False for the XLA path")
+    interpret = _default_interpret(interpret)
     Sp = _pad_to(S, 128)
     Bp = _pad_to(B, b_tile)
     NCp = _pad_to(NC, 8)
@@ -227,6 +292,106 @@ def cea_scan_multi(class_ids: jnp.ndarray, m_all: jnp.ndarray,
 # ---------------------------------------------------------------------------
 
 
+def _tile_bytes(*shape: int) -> int:
+    """VMEM bytes of a 4-byte array, last two dims padded to the (8, 128)
+    tile."""
+    lead = int(np.prod(shape[:-2], dtype=np.int64)) if len(shape) > 2 else 1
+    sub = shape[-2] if len(shape) > 1 else 1
+    return 4 * lead * _pad_to(sub, 8) * _pad_to(shape[-1], 128)
+
+
+def fused_vmem_bytes(*, A: int, W: int, S: int, NC: int, NQ: int, V: int,
+                     b_tile: int, t_tile: int, timed: bool, latest: bool,
+                     consume: bool, trace: bool) -> int:
+    """Estimated VMEM of one fused-kernel grid step (padded shapes).
+
+    Pipelined blocks count twice (double buffering); the ``(b_tile, W, S)``
+    ring counts for its in/out blocks, its scratch and the step's live
+    temporaries.
+    """
+    Sp, NCp, NQp = _pad_to(S, 128), _pad_to(NC, 8), _pad_to(NQ, 8)
+    ring = _tile_bytes(b_tile, W, Sp)
+    step = _tile_bytes(b_tile, t_tile)                    # one (b, t) row
+    blocks = (2 * _tile_bytes(b_tile, 1)                  # start, valid
+              + A * step                                  # attrs
+              + _tile_bytes(V, NCp) + _tile_bytes(NCp, Sp * Sp)
+              + _tile_bytes(NQp, Sp) + _tile_bytes(1, Sp)
+              + NQp * step                                # matches
+              + (step if trace else 0)
+              + (step                                     # event ts
+                 + 2 * _tile_bytes(b_tile, W)             # ts ring in/out
+                 + 2 * _tile_bytes(b_tile, 1) if timed else 0)
+              + (_tile_bytes(1, NQp) if latest else 0)
+              + (_tile_bytes(NQp, Sp) if consume else 0))
+    temps = ((A + 1) * step                               # loaded blocks
+             + _tile_bytes(b_tile, V) + _tile_bytes(b_tile, Sp * Sp)
+             + _tile_bytes(b_tile, Sp, Sp)                # gathered M
+             + 3 * ring                                   # C, C_new, mix
+             + _tile_bytes(b_tile * W, NQp)               # per-slot counts
+             + (_tile_bytes(b_tile, W) + _tile_bytes(b_tile, 1)
+                if timed else 0)
+             + (2 * _tile_bytes(b_tile, W, W)
+                + 2 * _tile_bytes(b_tile, W, NQp) if latest else 0))
+    return 2 * blocks + 3 * ring + temps
+
+
+def plan_pipeline(*, T: int, B: int, A: int, W: int, S: int, NC: int,
+                  NQ: int, V: int, timed: bool = False, per_lane: bool = False,
+                  latest: bool = False, consume: bool = False,
+                  trace: bool = False, impl: str = "fused",
+                  use_pallas: bool = True, interpret: Optional[bool] = None,
+                  b_tile: int = 8, t_tile: Optional[int] = None) -> Route:
+    """The :class:`Route` :func:`cer_pipeline` takes for these shapes.
+
+    Pure in its arguments and the attached device, so an engine can decide
+    it once at construction and record it.  ``T`` is the chunk (steps per
+    call), ``B`` the lanes, ``W`` the ring, ``S``/``NC``/``NQ`` the states,
+    classes and queries, ``V`` the indicator rows.
+    """
+    if impl not in IMPLS:
+        raise ValueError(f"impl must be one of {IMPLS}, got {impl!r}")
+    if t_tile is not None and T % t_tile != 0:
+        # a value invalid for the kernel must fail on every route
+        raise ValueError(f"t_tile must divide the chunk length: {t_tile} "
+                         f"vs T={T}")
+    if impl == "ref":
+        return REF_ROUTE
+    if not use_pallas:
+        return Route("xla", "use_pallas=False: the caller asked for XLA")
+    if impl == "unfused":
+        if per_lane or timed or latest or consume:
+            return Route("xla", "the unfused kernels take one scalar offset "
+                                "and count windows under ANY only")
+        return Route("unfused", "impl='unfused': the three-dispatch baseline",
+                     interpret=_default_interpret(interpret), b_tile=b_tile)
+    interpret = _default_interpret(interpret)
+    kind = _target_kind()
+    limit = vmem_limit(kind)
+    if t_tile is None:
+        # one block of the whole chunk, or lane-width blocks over a chunk
+        # padded with dead steps
+        t_tile = min(T, DEFAULT_T_TILE)
+    elif not interpret and t_tile != T and t_tile % 128 != 0:
+        raise ValueError(f"t_tile={t_tile} must be a multiple of 128 (or "
+                         f"all T={T} events) for the compiled kernel")
+    if W % 8 != 0:
+        return Route("xla", f"the ring has W={W} slots, not a multiple of "
+                            "the 8 f32 sublanes")
+    if not interpret and b_tile % 8 != 0 and _pad_to(B, b_tile) != b_tile:
+        raise ValueError(f"b_tile={b_tile} must be a multiple of 8 (or "
+                         f"cover all {B} lanes) for the compiled kernel")
+    vmem = fused_vmem_bytes(A=A, W=W, S=S, NC=NC, NQ=NQ, V=V, b_tile=b_tile,
+                            t_tile=t_tile, timed=timed, latest=latest,
+                            consume=consume, trace=trace)
+    if vmem > limit:
+        return Route("xla", f"VMEM estimate {vmem} B for a ({b_tile}, {W}, "
+                            f"{_pad_to(S, 128)}) ring tile exceeds the "
+                            f"{limit} B scoped limit of {kind}")
+    return Route("pallas", f"the ({b_tile}, {W}, {_pad_to(S, 128)}) ring "
+                           f"tile fits VMEM ({vmem} of {limit} B on {kind})",
+                 interpret=interpret, b_tile=b_tile, t_tile=t_tile)
+
+
 def cer_pipeline(attrs: jnp.ndarray,
                  specs: Sequence[Tuple[int, int, float]],
                  class_of: jnp.ndarray, class_ind: jnp.ndarray,
@@ -237,9 +402,7 @@ def cer_pipeline(attrs: jnp.ndarray,
                  event_ts: Optional[jnp.ndarray] = None,
                  start_pos: Union[int, jnp.ndarray] = 0,
                  valid_counts: Optional[jnp.ndarray] = None,
-                 impl: str = "fused", use_pallas: bool = True,
-                 interpret: Optional[bool] = None, b_tile: int = 8,
-                 t_tile: Optional[int] = None,
+                 route: Route,
                  return_trace: bool = False,
                  latest_q: Optional[jnp.ndarray] = None,
                  consume_sq: Optional[jnp.ndarray] = None
@@ -256,29 +419,30 @@ def cer_pipeline(attrs: jnp.ndarray,
     The fused Pallas kernel emits it as a third kernel output; the XLA and
     unfused paths already materialize it.
 
-    ``impl`` routes fused / unfused / ref (module docstring).  The fused
-    Pallas path needs W ≡ 0 (mod 8) and the VMEM budget to hold the
-    indicator + tables + state tile; otherwise it degrades to the fused XLA
-    computation (still one dispatch under the caller's jit).
-
-    ``t_tile``: events per fused-kernel grid step (None → the largest of
-    ``DEFAULT_T_TILE``, 2, 1 dividing T) — larger tiles amortize grid
-    sequencing; swept in ``benchmarks/perf_cer.py::fused_tile_sweep``.
+    Routing: ``route`` is the caller's :class:`Route`, planned by
+    :func:`plan_pipeline` from these shapes (engines plan it once at
+    construction and record it).  The fused Pallas path needs W ≡ 0
+    (mod 8) and the chip's scoped VMEM to hold the indicator, tables and
+    state tile; otherwise the route is the fused XLA computation (still one
+    dispatch under the caller's jit).  The route's ``t_tile`` is the events
+    per fused-kernel grid step; longer chunks are padded with dead steps to
+    a multiple of it.
 
     PARTITION BY lanes (DESIGN.md §6): ``start_pos`` may also be a ``(B,)``
     vector of per-lane substream offsets, and ``valid_counts`` a ``(B,)``
     int32 vector marking each lane's dense prefix of real events this chunk
     (steps past it are exact no-ops for that lane).  The fused Pallas kernel
     and the fused-XLA/ref path support both; the legacy unfused kernels are
-    scalar-only, so per-lane calls on that impl route to the XLA path.
+    scalar-only, so :func:`plan_pipeline` plans per-lane calls on that impl
+    onto the XLA path.
 
     Selection/consumption (DESIGN.md D2): ``latest_q`` ``(Q,)`` f32 marks
     LAST queries (their counts reduce to the latest live seed slot);
     ``consume_sq`` ``(Q, S)`` f32 maps each CONSUME BY ANY query to the
     packed states it clears after an emitting position.  Both default to
     ``None`` — the classic ANY graph, bit-identical to before.  The legacy
-    unfused kernels are count-only ANY; either operand routes that impl to
-    the fused-XLA path (like ``timed``/``per_lane`` do).
+    unfused kernels are count-only ANY; :func:`plan_pipeline` plans either
+    operand on that impl onto the fused-XLA path (like ``timed``/``per_lane``).
 
     Windows (DESIGN.md §9): pass either the legacy ``epsilon=`` (count
     window) or a :class:`repro.kernels.window.DeviceWindow` as ``window=``.
@@ -288,8 +452,6 @@ def cer_pipeline(attrs: jnp.ndarray,
     same form.  Time windows route to the fused Pallas kernel or the
     fused-XLA computation (the legacy unfused kernels are count-only).
     """
-    if impl not in IMPLS:
-        raise ValueError(f"impl must be one of {IMPLS}, got {impl!r}")
     if window is None:
         if epsilon is None:
             raise ValueError("cer_pipeline needs epsilon= or window=")
@@ -306,78 +468,41 @@ def cer_pipeline(attrs: jnp.ndarray,
             # the kernel, or silently mis-evict when T == B
             raise ValueError(f"event_ts must be (T, B) = ({T}, {B}) like "
                              f"attrs, got {event_ts.shape}")
-    # validate before impl routing: the XLA fallbacks ignore t_tile, but a
-    # value invalid for the kernel must fail on every backend, not only TPU
-    if t_tile is not None and T % t_tile != 0:
-        raise ValueError(f"t_tile must divide the chunk length: {t_tile} "
-                         f"vs T={T}")
     NC, S, _ = m_all.shape
     c_ring = c0["C"] if timed else c0
-    W = c_ring.shape[1]
-    per_lane = _is_lane_vector(start_pos) or valid_counts is not None
-    semantic = latest_q is not None or consume_sq is not None
+    NQ = finals_q.shape[0]
 
-    if impl == "ref" or (impl == "fused" and not use_pallas):
+    if route.path == "xla":
         return _pipeline_xla(attrs, specs, class_of, m_all, finals_q, c0,
                              init_mask, epsilon, start_pos, valid_counts,
                              return_trace, window=window, event_ts=event_ts,
                              latest_q=latest_q, consume_sq=consume_sq)
 
-    if impl == "unfused":
-        if per_lane or timed or semantic:
-            # the legacy 3-dispatch kernels take a scalar SMEM offset only
-            # and implement the count eviction rule under ANY semantics only
-            return _pipeline_xla(attrs, specs, class_of, m_all, finals_q,
-                                 c0, init_mask, epsilon, start_pos,
-                                 valid_counts, return_trace, window=window,
-                                 event_ts=event_ts, latest_q=latest_q,
-                                 consume_sq=consume_sq)
+    if route.path == "unfused":
         # legacy 3-dispatch path: bits kernel → gather → scan kernel
         bits = bitvector(attrs.reshape(T * B, A), specs,
-                         use_pallas=use_pallas, interpret=interpret)
+                         interpret=route.interpret)
         class_ids = class_of[bits].reshape(T, B)
         matches, c_fin = cea_scan_multi(
             class_ids, m_all, finals_q, c0, init_mask=init_mask,
-            epsilon=epsilon, start_pos=start_pos, use_pallas=use_pallas,
-            interpret=interpret, b_tile=b_tile)
+            epsilon=epsilon, start_pos=start_pos,
+            interpret=route.interpret, b_tile=route.b_tile)
         if return_trace:
             return matches, c_fin, class_ids.astype(jnp.int32)
         return matches, c_fin
 
-    # --- impl == "fused" ----------------------------------------------------
-    interpret = (not _on_tpu()) if interpret is None else interpret
-    if t_tile is None:
-        t_tile = max(tt for tt in (DEFAULT_T_TILE, 2, 1) if T % tt == 0)
-    NQ = finals_q.shape[0]
-    V = class_ind.shape[0]
+    # --- the fused Pallas kernel ---------------------------------------------
+    b_tile, t_tile = route.b_tile, route.t_tile
     Sp = _pad_to(S, 128)
     NCp = _pad_to(NC, 8)
     NQp = _pad_to(NQ, 8)
-    vmem = 4 * (3 * b_tile * W * Sp            # c_in + c_out + scratch
-                + V * NCp + V * b_tile         # indicator + one-hot temp
-                + NCp * Sp * Sp + NQp * Sp     # tables
-                + b_tile * Sp * Sp             # gathered-M temp
-                + b_tile * W * NQp             # per_q temp
-                + b_tile * t_tile * (A + NQp)  # attrs + matches blocks
-                + (2 + (t_tile if return_trace else 0))
-                * b_tile                       # start/valid[/trace block]
-                + (3 * b_tile * W + 4 * b_tile + b_tile * t_tile
-                   if timed else 0)            # ts ring ×3 + ovf + ts block
-                + (b_tile * W * W + b_tile * W * NQp + NQp
-                   if latest_q is not None else 0)   # age cmp + keep + flags
-                + (NQp * Sp + b_tile * Sp
-                   if consume_sq is not None else 0))  # map + clear temp
-    if W % 8 != 0 or vmem > VMEM_BYTES:
-        return _pipeline_xla(attrs, specs, class_of, m_all, finals_q, c0,
-                             init_mask, epsilon, start_pos, valid_counts,
-                             return_trace, window=window, event_ts=event_ts,
-                             latest_q=latest_q, consume_sq=consume_sq)
-
     Bp = _pad_to(B, b_tile)
-    a_pad = jnp.pad(jnp.moveaxis(attrs, 0, 1),
-                    ((0, Bp - B), (0, 0), (0, 0)))            # (Bp, T, A)
+    Tp = _pad_to(T, t_tile)                      # padded steps are dead
+    a_pad = jnp.pad(jnp.transpose(attrs, (2, 1, 0)),
+                    ((0, 0), (0, Bp - B), (0, Tp - T)))        # (A, Bp, Tp)
     ind_pad = jnp.pad(class_ind, ((0, 0), (0, NCp - NC)))
-    m_pad = jnp.pad(m_all, ((0, NCp - NC), (0, Sp - S), (0, Sp - S)))
+    m_flat = jnp.pad(m_all, ((0, NCp - NC), (0, Sp - S), (0, Sp - S))
+                     ).reshape(NCp, Sp * Sp)
     f_pad = jnp.pad(finals_q.astype(jnp.float32),
                     ((0, NQp - NQ), (0, Sp - S)))
     i_pad = jnp.pad(init_mask.astype(jnp.float32), (0, Sp - S))[None, :]
@@ -389,8 +514,7 @@ def cer_pipeline(attrs: jnp.ndarray,
     if timed:
         time_kw = dict(
             time_size=float(window.size),
-            event_ts=jnp.pad(jnp.asarray(event_ts, jnp.float32).T,
-                             ((0, Bp - B), (0, 0))),
+            event_ts=jnp.pad(event_ts.T, ((0, Bp - B), (0, Tp - T))),
             ts_ring0=jnp.pad(c0["ts"], ((0, Bp - B), (0, 0)),
                              constant_values=TS_EMPTY),
             ovf0=jnp.pad(c0["ovf"].astype(jnp.int32)[:, None],
@@ -405,90 +529,19 @@ def cer_pipeline(attrs: jnp.ndarray,
             ((0, NQp - NQ), (0, Sp - S)))
 
     res = fused_scan_pallas(
-        a_pad, ind_pad, m_pad, f_pad, i_pad, c_pad, start_lanes, valid_lanes,
-        specs=tuple(specs), epsilon=epsilon, b_tile=b_tile, t_tile=t_tile,
-        interpret=interpret, emit_trace=return_trace, **time_kw, **sem_kw)
+        a_pad, ind_pad, m_flat, f_pad, i_pad, c_pad, start_lanes,
+        valid_lanes, specs=tuple(specs), epsilon=epsilon, b_tile=b_tile,
+        t_tile=t_tile, interpret=route.interpret, emit_trace=return_trace,
+        **time_kw, **sem_kw)
     matches, c_fin = res[0], res[1]
     c_out = c_fin[:B, :, :S]
     if timed:
         c_out = {"C": c_out, "ts": res[2][:B],
                  "ovf": res[3][:B, 0].astype(bool)}
-    out = jnp.moveaxis(matches[:B, :, :NQ], 0, 1), c_out
+    out = jnp.transpose(matches[:NQ, :B, :T], (2, 1, 0)), c_out
     if return_trace:
-        return out + (res[-1][:B].T,)
+        return out + (res[-1][:B, :T].T,)
     return out
-
-
-def arena_block_update(cells0, class_ids, hits, start, valid_counts, *,
-                       lay, ptab, finals_sq, n_seg: int = 1,
-                       expire: Optional[jnp.ndarray] = None,
-                       consume: Optional[jnp.ndarray] = None,
-                       use_pallas: bool = False,
-                       interpret: Optional[bool] = None, b_tile: int = 8):
-    """Block tECS builder over one chunk — Pallas kernel vs jnp oracle.
-
-    cells0: four (B, W, S) int32 arrays (node id / is-union / left /
-    right — the chunk-start cell table).  class_ids: (T, B) int32.
-    hits: (T, B, Q) bool/int32.  start/valid_counts: (B,) int32.  ptab:
-    (C, S, K, 3) packed predecessor tables
-    (:func:`repro.kernels.ref.pack_pred_tables`).  n_seg: parallel chunk
-    segments (:func:`repro.kernels.ref.pick_segments`).  expire: optional
-    (T, B, W) precomputed time-window eviction masks (DESIGN.md §9; None
-    keeps the count-window single-slot rule).  consume: optional
-    (T, B, S) CONSUME BY ANY clear masks, precomputed from the counting
-    scan's matches — cells of the flagged states drop after each event's
-    roots (emit-then-clear, mirroring the counting kernels).  Returns
-    ``(cells_T, valid, left, right, roots)`` — record arrays (T, B, M) on
-    virtual node ids; allocation and the store update happen vectorized
-    downstream (``tecs_arena.arena_scan_block``).
-
-    Routing: the Pallas kernel (:mod:`repro.kernels.arena_update`) engages
-    only on TPU — in interpret mode it is strictly slower than the XLA
-    oracle, so off-TPU callers get :func:`repro.kernels.ref.arena_build_ref`
-    unless ``interpret=True`` forces the kernel for parity tests.  Both
-    paths run the same :func:`repro.kernels.ref.arena_block_step` over the
-    same segmented operands.
-    """
-    T, B = class_ids.shape
-    start = jnp.broadcast_to(jnp.asarray(start, jnp.int32), (B,))
-    valid_counts = jnp.broadcast_to(jnp.asarray(valid_counts, jnp.int32),
-                                    (B,))
-    if not use_pallas or (interpret is None and not _on_tpu()):
-        return ref.arena_build_ref(cells0, class_ids, hits, start,
-                                   valid_counts, lay=lay, ptab=ptab,
-                                   finals_sq=finals_sq, n_seg=n_seg,
-                                   expire=expire, consume=consume)
-    interpret = False if interpret is None else interpret
-    xs, cells0_seg = ref.segment_operands(cells0, class_ids, hits, start,
-                                          valid_counts, lay=lay,
-                                          n_seg=n_seg, expire=expire,
-                                          consume=consume)
-    cls_s, hit_s, j_s, live_s, vb_s = xs[:5]
-    extra = list(xs[5:])
-    exp_s = extra.pop(0) if expire is not None else None
-    con_s = extra.pop(0) if consume is not None else None
-    Bn = cls_s.shape[1]
-    Bp = _pad_to(Bn, b_tile)
-    pads = ((0, Bp - Bn), (0, 0), (0, 0))
-
-    def lane(x):                   # (steps, Bn, ...) → padded (Bp, steps, …)
-        x = jnp.moveaxis(jnp.asarray(x, jnp.int32), 0, 1)
-        return jnp.pad(x, pads[:x.ndim])
-
-    recs, roots, cells_fin = arena_update_pallas(
-        tuple(jnp.pad(c, pads, constant_values=ref.ARENA_NULL)
-              for c in cells0_seg),
-        lane(cls_s), lane(hit_s), lane(j_s),
-        lane(live_s),              # padded lanes are dead (live = 0)
-        lane(vb_s), lay=lay, ptab=ptab, finals_sq=finals_sq,
-        b_tile=b_tile, interpret=interpret,
-        expire_s=None if exp_s is None else lane(exp_s),
-        consume_s=None if con_s is None else lane(con_s))
-    recs = tuple(jnp.moveaxis(y[:Bn], 0, 1) for y in recs)
-    roots = jnp.moveaxis(roots[:Bn], 0, 1)
-    cells_fin = tuple(c[:Bn] for c in cells_fin)
-    return ref.assemble_records(cells_fin, recs, roots, T, B,
-                                lay=lay, n_seg=n_seg)
 
 
 def _pipeline_xla(attrs, specs, class_of, m_all, finals_q, c0, init_mask,

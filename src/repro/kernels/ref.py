@@ -22,7 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.tecs import BOTTOM, OUTPUT, UNION
-from .cea_scan import consume_clear, latest_slot_counts
+from .cea_scan import _HIGHEST, consume_clear, latest_slot_counts
 
 # op codes shared with the bit-vector kernel
 OP_EQ, OP_NE, OP_LT, OP_LE, OP_GT, OP_GE = range(6)
@@ -85,8 +85,9 @@ def cea_step_ref(C: jnp.ndarray, M: jnp.ndarray, seed_slot: jnp.ndarray,
     init_oh = (jnp.arange(S) == init_state).astype(C.dtype)
     C = C + seed_oh[None, :, None] * init_oh[None, None, :]
     # advance every live run by this event: counting-semiring matmul
-    C = jnp.einsum("bws,bst->bwt", C, M)
-    matches = jnp.einsum("bws,s->b", C, finals.astype(C.dtype))
+    C = jnp.einsum("bws,bst->bwt", C, M, precision=_HIGHEST)
+    matches = jnp.einsum("bws,s->b", C, finals.astype(C.dtype),
+                         precision=_HIGHEST)
     return C, matches
 
 
@@ -179,9 +180,9 @@ def cea_scan_multi_ref(C0, M_all: jnp.ndarray,
         clear = (seed | expire).astype(C.dtype)
         C2 = C * (1.0 - clear)[:, :, None] \
             + seed.astype(C.dtype)[:, :, None] * im[None, None, :]
-        C2 = jnp.einsum("bws,bst->bwt", C2, M)
+        C2 = jnp.einsum("bws,bst->bwt", C2, M, precision=_HIGHEST)
         if latest_q is None:
-            m = jnp.einsum("bws,qs->bq", C2, fq)
+            m = jnp.einsum("bws,qs->bq", C2, fq, precision=_HIGHEST)
         else:
             m = latest_slot_counts(C2, fq, j, latest_q)
         tsr2 = jnp.where(seed, ts_t[:, None], tsr)
@@ -230,9 +231,9 @@ def _scan_multi_count_ref(C0: jnp.ndarray, M_all: jnp.ndarray,
         clear = jnp.maximum(seed, expire)                          # (B, W)
         C2 = C * (1.0 - clear)[:, :, None] \
             + seed[:, :, None] * im[None, None, :]
-        C2 = jnp.einsum("bws,bst->bwt", C2, M)
+        C2 = jnp.einsum("bws,bst->bwt", C2, M, precision=_HIGHEST)
         if latest_q is None:
-            m = jnp.einsum("bws,qs->bq", C2, fq)
+            m = jnp.einsum("bws,qs->bq", C2, fq, precision=_HIGHEST)
         else:
             m = latest_slot_counts(C2, fq, j, latest_q)
         if valid is not None:
@@ -262,9 +263,8 @@ def _scan_multi_count_ref(C0: jnp.ndarray, M_all: jnp.ndarray,
 #   1. a minimal sequential recurrence over the chunk — ONLY the per-cell
 #      attribute table (node id / is-union / union children, four (B, W, S)
 #      int32 arrays) is carried, one gather + one unrolled union-gadget
-#      fold per predecessor depth per event (`arena_block_step`; the Pallas
-#      kernel in kernels/arena_update.py runs the same function with the
-#      table in VMEM), emitting the cell-table *trace*; and
+#      fold per predecessor depth per event (`arena_block_step`), emitting
+#      the cell-table *trace*; and
 #
 #   2. fully vectorized record reconstruction over the whole chunk
 #      (`arena_records_from_trace`): the same helpers, `jax.vmap`-ed over
@@ -284,10 +284,8 @@ def _scan_multi_count_ref(C0: jnp.ndarray, M_all: jnp.ndarray,
 # references in one vectorized pass, and lands every SoA field with one
 # batched store update per chunk (tecs_arena.arena_scan_block).
 #
-# Both execution paths (jnp scan below, Pallas kernel) call the same step
-# function, so kernel/oracle parity holds by construction, and the record
-# reconstruction consumes the emitted trace — the allocation plan can never
-# diverge from the recurrence.
+# The record reconstruction consumes the emitted trace, so the allocation
+# plan can never diverge from the recurrence.
 #
 # The record regions run over target states 1..S−1 only: the dead state 0
 # never has predecessor edges, so its cells can never allocate — dropping
@@ -746,9 +744,7 @@ def arena_block_step(cells, cls_t, hit_t, j, live, vbase, *,
     partitioned scatter) skip fold, emission and roots at runtime and
     return the cell table unchanged with all-invalid records.  Both
     branches emit identical rows because the records are canonical:
-    ``left``/``right`` are NULL wherever ``valid`` is 0.  Pallas kernels
-    keep both flags off — ``cond`` does not lower there — and pay every
-    step unconditionally.
+    ``left``/``right`` are NULL wherever ``valid`` is 0.
     """
     B = cls_t.shape[0]
     Q = lay.Q
@@ -828,8 +824,7 @@ def pick_segments(T: int, W: int, max_seg: int = 8) -> int:
     NOTE: on CPU XLA the builder step is bandwidth-bound, so the replay
     overhead loses — measured slower for every n > 1 — and the default
     everywhere is n_seg = 1.  The knob exists for accelerator backends
-    where shorter grids amortize per-step launch cost (the Pallas kernel
-    grid shrinks by the same factor).
+    where shorter scans amortize per-step launch cost.
     """
     best = 1
     for n in range(2, max_seg + 1):
@@ -854,12 +849,8 @@ def arena_build_ref(cells0, class_ids, hits, start, valid_counts, *,
     each event's roots, see :func:`arena_block_step`.  Returns
     ``(cells_T, valid, left, right, roots)`` with the record
     arrays (T, B, M) int32 in slot-layout order and roots (T, B, Q), on
-    virtual ids.
-
-    The Pallas kernel path (kernels/arena_update.py) runs the same step
-    over the same segmented operands with the cell table in VMEM; the
-    shared preparation/assembly lives in :func:`segment_operands` /
-    :func:`assemble_records`.
+    virtual ids.  The arena's only builder on every platform
+    (``tecs_arena.arena_scan_block``).
     """
     xs, cells0_seg = segment_operands(cells0, class_ids, hits, start,
                                       valid_counts, lay=lay, n_seg=n_seg,

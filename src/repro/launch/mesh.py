@@ -8,8 +8,14 @@ from __future__ import annotations
 
 import jax
 
-from ..jaxcompat import make_mesh as _make_mesh
-from ..jaxcompat import use_mesh  # re-exported for callers  # noqa: F401
+#: ambient-mesh context manager (``with use_mesh(mesh): ...``)
+use_mesh = jax.set_mesh
+
+
+def _make_mesh(shape, axes, devices=None):
+    return jax.make_mesh(
+        shape, axes, devices=devices,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -114,7 +114,7 @@ def main() -> None:
         if args.service:
             from ..runtime import EventValidator, StreamService
             from ..vector import PartitionedStreamingEngine, VectorEngine
-            ve = VectorEngine(q, use_pallas=False)
+            ve = VectorEngine(q)
             pse = PartitionedStreamingEngine(
                 ve, q.query.partition_by, chunk_len=16,
                 num_lanes=max(4, args.lanes))

@@ -15,7 +15,6 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..jaxcompat import current_mesh, shard_map
 from ..sharding import with_logical_constraint as wlc
 from .config import ModelConfig, MoEConfig
 from .layers import Params, dense_init, mlp, mlp_init
@@ -80,8 +79,8 @@ def moe_apply(p: Params, cfg: ModelConfig, x: jnp.ndarray
       §Perf logs the progression.
     * **off-mesh (host tests)**: the same math, single shard.
     """
-    mesh = current_mesh()
-    if mesh is not None and "model" in mesh.axis_names:
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.empty and "model" in mesh.axis_names:
         return _moe_sharded(p, cfg, x, mesh)
     return _moe_global(p, cfg, x)
 
@@ -284,10 +283,9 @@ def _moe_sharded(p: Params, cfg: ModelConfig, x: jnp.ndarray, mesh
             aux = jax.lax.pmean(aux, batch_axes)
         return y.reshape(Bl, S, d), aux
 
-    y, aux = shard_map(
-        body, mesh,
-        (p_specs, x_spec),
-        (x_spec, P()),
+    y, aux = jax.shard_map(
+        body, mesh=mesh, in_specs=(p_specs, x_spec),
+        out_specs=(x_spec, P()), check_vma=False,
     )(p, x)
     return y, aux
 
@@ -384,9 +382,9 @@ def _moe_decode_stationary(p: Params, cfg: ModelConfig, x: jnp.ndarray, mesh
     for a in batch_axes:
         n_b *= sizes[a]
     out_spec = x_spec if (batch_axes and B % n_b == 0) else P(None, None, None)
-    y, aux = shard_map(
-        body, mesh,
-        (p_specs, P(None, None, None)),   # tokens replicated
-        (out_spec, P()),
+    y, aux = jax.shard_map(
+        body, mesh=mesh,
+        in_specs=(p_specs, P(None, None, None)),   # tokens replicated
+        out_specs=(out_spec, P()), check_vma=False,
     )(p, x)
     return y, aux

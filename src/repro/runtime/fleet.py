@@ -49,7 +49,7 @@ import numpy as np
 
 from ..core.predicates import AtomRegistry
 from ..core.query import compile_query
-from ..kernels import ref
+from ..kernels import ops, ref
 from ..kernels import window as wkern
 from ..vector import tecs_arena
 from ..vector.multiquery import (MultiQueryEngine, Packing, build_packing,
@@ -152,8 +152,8 @@ def _make_data_step(cache: CompileCache, key: tuple,
 
 def _make_arena_step(cache: CompileCache, key: tuple, atables, specs,
                      class_of, class_ind, m_all, finals_q, init_mask,
-                     window, impl, use_pallas, b_tile,
-                     arena_impl, latest_q=None, consume_sq=None) -> Callable:
+                     window, route, arena_impl, latest_q=None,
+                     consume_sq=None) -> Callable:
     """Counting + tECS-arena step with closed-over tables.
 
     The block arena's static layout is computed from table *values*
@@ -168,8 +168,7 @@ def _make_arena_step(cache: CompileCache, key: tuple, atables, specs,
             atables, state["arena"], attrs, state["C"], specs=specs,
             class_of=class_of, class_ind=class_ind, m_all=m_all,
             finals_q=finals_q, init_mask=init_mask, window=window,
-            start=start_pos, gbase=gbase, impl=impl,
-            use_pallas=use_pallas, b_tile=b_tile, arena_impl=arena_impl,
+            start=start_pos, gbase=gbase, route=route, arena_impl=arena_impl,
             event_ts=event_ts, latest_q=latest_q, consume_sq=consume_sq)
         return counts, {"C": C, "arena": arena}, roots
 
@@ -240,9 +239,17 @@ class _FleetStreamEngine(StreamingVectorEngine):
                 lambda c, k: _make_arena_step(
                     c, k, self._arena_tables, self._specs, self._class_of,
                     self._class_ind, self._m_all, self._finals_q,
-                    self._init_mask, self.window, self.impl,
-                    self._use_pallas, self._b_tile, self.arena_impl,
+                    self._init_mask, self.window, self.routes["scan"],
+                    self.arena_impl,
                     latest_q=self._latest_q, consume_sq=self._consume_sq))
+
+    def _plan_routes(self, per_lane: bool = False):
+        routes = super()._plan_routes(per_lane)
+        routes["scan"] = ops.Route(
+            "xla", "fleet steps take the packed tables as traced operands "
+                   "so one executable serves every packing of a bucket "
+                   "geometry; the fused kernel compiles tables in")
+        return routes
 
     def feed_attrs(self, attrs, event_ts=None):
         a = attrs.shape[-1]
@@ -432,8 +439,7 @@ class QueryFleet:
     def _build_engine(self, bucket: _Bucket,
                       packing: Packing) -> _FleetStreamEngine:
         engine = MultiQueryEngine.from_packing(
-            packing, epsilon=self.epsilon, use_pallas=False, impl="ref",
-            arena_impl=self.arena_impl,
+            packing, epsilon=self.epsilon, arena_impl=self.arena_impl,
             max_window_events=self.max_window_events)
         if (engine.window.kind, float(engine.window.size),
                 engine.window.time_attr) != bucket.key:

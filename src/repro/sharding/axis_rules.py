@@ -176,9 +176,8 @@ def divisible_spec(spec: P, shape: Tuple[int, ...], axis_sizes: Dict[str, int]
 def with_logical_constraint(x: jax.Array,
                             logical_axes: Sequence[Optional[str]]) -> jax.Array:
     """Annotate activation sharding; no-op outside a `jax.set_mesh` context."""
-    from ..jaxcompat import current_mesh
-    mesh = current_mesh()
-    if mesh is None:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return x
     try:
         # inside shard_map the axes are Manual: layout is already explicit

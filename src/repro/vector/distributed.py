@@ -27,7 +27,6 @@ import numpy as np
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from ..jaxcompat import shard_map as _shard_map
 from ..kernels import ops
 
 
@@ -50,10 +49,10 @@ def sharded_cea_scan(mesh: Mesh, class_ids, m_all, finals, c0, *,
         return ops.cea_scan(ids, m, f, c, epsilon=epsilon,
                             start_pos=sp[0], use_pallas=use_pallas)
 
-    return _shard_map(
-        local_scan, mesh,
-        (P(None, axes), P(), P(), P(axes), P()),
-        (P(None, axes), P(axes)),
+    return jax.shard_map(
+        local_scan, mesh=mesh,
+        in_specs=(P(None, axes), P(), P(), P(axes), P()),
+        out_specs=(P(None, axes), P(axes)), check_vma=False,
     )(class_ids, m_all, finals, c0, ops._start_arr(start_pos))
 
 
@@ -72,14 +71,20 @@ def sharded_cer_pipeline(mesh: Mesh, attrs, specs, class_of, class_ind,
     specs = tuple(specs)
 
     def local_pipeline(a, co, ci, m, fq, c, im, sp):
+        T, B, A = a.shape
+        route = ops.plan_pipeline(
+            T=T, B=B, A=A, W=c.shape[1], S=m.shape[1], NC=m.shape[0],
+            NQ=fq.shape[0], V=ci.shape[0], impl=impl, use_pallas=use_pallas,
+            b_tile=b_tile)
         return ops.cer_pipeline(a, specs, co, ci, m, fq, c, init_mask=im,
-                                epsilon=epsilon, start_pos=sp[0], impl=impl,
-                                use_pallas=use_pallas, b_tile=b_tile)
+                                epsilon=epsilon, start_pos=sp[0],
+                                route=route)
 
-    return _shard_map(
-        local_pipeline, mesh,
-        (P(None, axes, None), P(), P(), P(), P(), P(axes), P(), P()),
-        (P(None, axes, None), P(axes)),
+    return jax.shard_map(
+        local_pipeline, mesh=mesh,
+        in_specs=(P(None, axes, None), P(), P(), P(), P(), P(axes), P(),
+                  P()),
+        out_specs=(P(None, axes, None), P(axes)), check_vma=False,
     )(attrs, class_of, class_ind, m_all, finals_q, c0, init_mask,
       ops._start_arr(start_pos))
 
@@ -142,10 +147,10 @@ def route_by_partition(mesh: Mesh, events: jnp.ndarray, keys: jnp.ndarray,
         return tuple(exchange(x) for x in (ev, *pls)) + (keep,)
 
     # returns (routed, keep) or (routed, routed_payload, keep)
-    return _shard_map(
-        local_route, mesh,
-        (P(axes),) * (3 + len(extra)),
-        (P(axes),) * (2 + len(extra)),
+    return jax.shard_map(
+        local_route, mesh=mesh,
+        in_specs=(P(axes),) * (3 + len(extra)),
+        out_specs=(P(axes),) * (2 + len(extra)), check_vma=False,
     )(events, keys, drop, *extra)
 
 

@@ -68,6 +68,26 @@ def _fallback_base(window: "wkern.DeviceWindow", start_pos):
     return None
 
 
+def plan_oneshot(engine, attrs_shape, start_pos=0, trace: bool = False
+                 ) -> "ops.Route":
+    """Route of a one-shot pipeline call on ``engine`` (a VectorEngine or
+    MultiQueryEngine) for ``(T, B, A)`` attributes, recorded on the engine
+    as ``engine.routes["scan"]``."""
+    t = engine.tables
+    T, B, A = attrs_shape
+    nq = t.finals.shape[0] if t.finals.ndim == 2 else 1
+    route = ops.plan_pipeline(
+        T=T, B=B, A=A, W=engine.ring, S=t.m_all.shape[1],
+        NC=t.m_all.shape[0], NQ=nq, V=t.class_ind.shape[0],
+        timed=engine.window.is_time,
+        per_lane=getattr(start_pos, "ndim", 0) >= 1,
+        latest=t.latest_q is not None, consume=t.consume_sq is not None,
+        trace=trace, impl=engine.impl, use_pallas=engine.use_pallas,
+        b_tile=engine.b_tile)
+    engine.routes = {"scan": route}
+    return route
+
+
 @dataclass
 class VectorQueryTables:
     """Device-resident tables for one compiled query.
@@ -137,6 +157,8 @@ class VectorEngine:
         # arena_impl: "block" (vectorized allocation, DESIGN.md §8) or
         # "fold" (per-event reference fold, kept for parity testing)
         self.arena_impl = tecs_arena.check_arena_impl(arena_impl)
+        #: stage → :class:`~repro.kernels.ops.Route` of the last call
+        self.routes: Dict[str, ops.Route] = {}
         init_mask = np.zeros(self.symbolic.num_states, np.float32)
         init_mask[self.symbolic.initial] = 1.0
         sem = self.semantics
@@ -218,8 +240,8 @@ class VectorEngine:
             attrs, self.encoder.specs, t.class_of, t.class_ind, t.m_all,
             t.finals[None, :], state, init_mask=t.init_mask,
             window=self.window, event_ts=event_ts, start_pos=start_pos,
-            impl=self.impl, use_pallas=self.use_pallas, b_tile=self.b_tile,
-            latest_q=t.latest_q, consume_sq=t.consume_sq)
+            latest_q=t.latest_q, consume_sq=t.consume_sq,
+            route=plan_oneshot(self, attrs.shape, start_pos))
         return matches[:, :, 0], state
 
     def run(self, streams: Sequence[Sequence[Event]],

@@ -517,6 +517,8 @@ class MultiQueryEngine:
             "fused" if use_pallas else "ref")
         from . import tecs_arena
         self.arena_impl = tecs_arena.check_arena_impl(arena_impl)
+        #: stage → :class:`~repro.kernels.ops.Route` of the last call
+        self.routes = {}
         self.tables = packing.tables
         sems = [c.semantics for c in self.compiled]
         self.strategies = tuple(c.query.strategy for c in self.compiled)
@@ -569,13 +571,14 @@ class MultiQueryEngine:
 
     def pipeline(self, attrs, state, start_pos=0, event_ts=None):
         """Single-dispatch fused path: (T, B, A) → (matches (T, B, Q), st')."""
+        from .engine import plan_oneshot
         t = self.tables
         return ops.cer_pipeline(
             attrs, self.encoder.specs, t.class_of, t.class_ind, t.m_all,
             t.finals, state, init_mask=t.init_mask, window=self.window,
-            event_ts=event_ts, start_pos=start_pos, impl=self.impl,
-            use_pallas=self.use_pallas, b_tile=self.b_tile,
-            latest_q=t.latest_q, consume_sq=t.consume_sq)
+            event_ts=event_ts, start_pos=start_pos,
+            latest_q=t.latest_q, consume_sq=t.consume_sq,
+            route=plan_oneshot(self, attrs.shape, start_pos))
 
     def encode_ts(self, streams, base_pos: Optional[int] = 0):
         """(attrs, event_ts | None) per the window — see VectorEngine."""

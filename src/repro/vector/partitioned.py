@@ -129,6 +129,7 @@ class PartitionedStreamingEngine(StreamingVectorEngine):
         # state via our _init_full_state override (lane tables + arena in
         # one shot — no throwaway parent-shaped allocation)
         self.num_lanes = int(num_lanes)
+        self.lane_cap = int(lane_cap) if lane_cap is not None else chunk_len
         super().__init__(engine, chunk_len, batch=num_lanes, impl=impl,
                          arena_capacity=arena_capacity,
                          arena_impl=arena_impl,
@@ -136,7 +137,6 @@ class PartitionedStreamingEngine(StreamingVectorEngine):
         if evict not in ("lru", "none"):
             raise ValueError(f"evict must be 'lru' or 'none', got {evict!r}")
         self.key_attrs = tuple(key_attrs)
-        self.lane_cap = int(lane_cap) if lane_cap is not None else chunk_len
         self.evict = evict
         self.stats = PartitionStats()
         self._hash_to_key: Dict[int, tuple] = {}
@@ -148,7 +148,12 @@ class PartitionedStreamingEngine(StreamingVectorEngine):
         self._chunk_idx = 0
         self._step = self._make_step()
 
+    @property
+    def _scan_steps(self) -> int:
+        return self.lane_cap
+
     def _make_step(self):
+        self.routes = self._plan_routes(per_lane=True)
         return jax.jit(self._part_step_impl, donate_argnums=(2,))
 
     # ------------------------------------------------------------------
@@ -262,11 +267,9 @@ class PartitionedStreamingEngine(StreamingVectorEngine):
             attrs_lanes, self._specs, self._class_of, self._class_ind,
             self._m_all, self._finals_q, C, init_mask=self._init_mask,
             window=self.window, event_ts=ts_lanes,
-            start_pos=lane_pos, valid_counts=n,
-            impl=self.impl, use_pallas=self._use_pallas,
-            b_tile=self._b_tile, return_trace=with_arena,
-            latest_q=self._latest_q,
-            consume_sq=self._consume_sq)                       # (cap, L, Q)
+            start_pos=lane_pos, valid_counts=n, return_trace=with_arena,
+            latest_q=self._latest_q, consume_sq=self._consume_sq,
+            route=self.routes["scan"])                         # (cap, L, Q)
         matches, C = pipe[0], pipe[1]
 
         # --- 4. relabel: routed-slot counts → chunk event order -----------
@@ -313,13 +316,13 @@ class PartitionedStreamingEngine(StreamingVectorEngine):
                 consume = jnp.einsum(
                     "tbq,qs->tbs", hitsq.astype(jnp.float32),
                     jnp.asarray(self._consume_sq, jnp.float32)
-                    [:Qa, :self._arena_tables.num_states]) > 0.5
+                    [:Qa, :self._arena_tables.num_states],
+                    precision=jax.lax.Precision.HIGHEST) > 0.5
             arena, roots = tecs_arena.run_arena_scan(
                 self._arena_tables, arena, trace, gpos_lanes,
                 lane_pos, n, hitsq, epsilon=self.epsilon,
                 expire=expire, consume=consume,
-                arena_impl=self.arena_impl, use_pallas=self._use_pallas,
-                b_tile=self._b_tile)
+                arena_impl=self.arena_impl)
             rr = jnp.concatenate(
                 [jnp.moveaxis(roots, 0, 1).reshape(L * cap, Qa),
                  jnp.full((1, Qa), tecs_arena.NULL, jnp.int32)])

@@ -327,9 +327,34 @@ class StreamingVectorEngine:
         # state ring donated: steady-state streaming allocates nothing new
         self._step = self._make_step()
 
+    #: events each lane advances per compiled step (the partitioned
+    #: subclass scans ``lane_cap`` routed slots instead)
+    @property
+    def _scan_steps(self) -> int:
+        return self.chunk_len
+
+    def _plan_routes(self, per_lane: bool = False) -> Dict[str, "ops.Route"]:
+        """Kernel-or-XLA route of each compiled stage, decided from the
+        shapes (:func:`repro.kernels.ops.plan_pipeline`) — recorded so no
+        switch to XLA or to interpret mode goes unseen."""
+        A = len(self.encoder.attrs)
+        routes = {"scan": ops.plan_pipeline(
+            T=self._scan_steps, B=self.batch, A=A, W=self._ring,
+            S=self._m_all.shape[1], NC=self._m_all.shape[0],
+            NQ=self._finals_q.shape[0], V=self._class_ind.shape[0],
+            timed=self.window.is_time, per_lane=per_lane,
+            latest=self._latest_q is not None,
+            consume=self._consume_sq is not None,
+            trace=self.arena_capacity is not None, impl=self.impl,
+            use_pallas=self._use_pallas, b_tile=self._b_tile)}
+        if self.arena_capacity is not None:
+            routes["arena"] = ops.ARENA_ROUTE
+        return routes
+
     def _make_step(self):
         """(Re)build the jitted step — called at init and after a ring
         regrow invalidates the compiled executable's shapes."""
+        self.routes = self._plan_routes()
         return jax.jit(
             self._arena_step_impl if self.arena_capacity is not None
             else self._step_impl, donate_argnums=(1,))
@@ -350,9 +375,8 @@ class StreamingVectorEngine:
             attrs, self._specs, self._class_of, self._class_ind, self._m_all,
             self._finals_q, state, init_mask=self._init_mask,
             window=self.window, event_ts=event_ts,
-            start_pos=start_pos, impl=self.impl,
-            use_pallas=self._use_pallas, b_tile=self._b_tile,
-            latest_q=self._latest_q, consume_sq=self._consume_sq)
+            start_pos=start_pos, latest_q=self._latest_q,
+            consume_sq=self._consume_sq, route=self.routes["scan"])
 
     def _arena_step_impl(self, attrs: jnp.ndarray, state: dict,
                          start_pos: jnp.ndarray, gbase: jnp.ndarray,
@@ -369,8 +393,7 @@ class StreamingVectorEngine:
             class_ind=self._class_ind, m_all=self._m_all,
             finals_q=self._finals_q, init_mask=self._init_mask,
             window=self.window, start=start_pos, gbase=gbase,
-            impl=self.impl, use_pallas=self._use_pallas,
-            b_tile=self._b_tile, arena_impl=self.arena_impl,
+            route=self.routes["scan"], arena_impl=self.arena_impl,
             event_ts=event_ts, latest_q=self._latest_q,
             consume_sq=self._consume_sq)
         return counts, {"C": C, "arena": arena}, roots

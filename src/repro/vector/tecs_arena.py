@@ -500,12 +500,29 @@ def _ptab(tables: ArenaTables) -> jnp.ndarray:
     return pt
 
 
+#: bytes one chunk's ``(T, lanes, M)`` int32 record array may take; the
+#: builder keeps about ten arrays of that size live, so wide batches run
+#: in lane groups sized to this budget (:func:`arena_lane_group`)
+ARENA_RECORD_BYTES = 1 << 28
+
+
+def arena_lane_group(T: int, B: int, M: int) -> int:
+    """Lanes the block builder processes at once: the largest divisor of
+    ``B`` whose ``(T, lanes, M)`` record array fits
+    :data:`ARENA_RECORD_BYTES` (at least one lane)."""
+    per_lane = 4 * T * M
+    best = 1
+    for g in range(1, B + 1):
+        if B % g == 0 and g * per_lane <= ARENA_RECORD_BYTES:
+            best = g
+    return best
+
+
 def arena_scan_block(tables: ArenaTables, arena: dict,
                      class_ids: jnp.ndarray, gpos: jnp.ndarray,
                      start: jnp.ndarray, valid: jnp.ndarray,
                      hits: jnp.ndarray, *, epsilon: int, expire=None,
-                     consume=None, use_pallas: bool = False,
-                     interpret: Optional[bool] = None, b_tile: int = 8,
+                     consume=None,
                      n_seg: int = 1) -> Tuple[dict, jnp.ndarray]:
     """Block-vectorized :func:`arena_scan` — same contract, ~1000× less
     per-event write traffic (DESIGN.md §8).
@@ -521,9 +538,9 @@ def arena_scan_block(tables: ArenaTables, arena: dict,
        statically-tabulated predecessor edges through the union gadgets
        (unrolled over the fold depth K, the relevant final states and the
        chain axis — no traced inner scans) and emits fixed-layout node
-       *records* on a virtual id space (``ops.arena_block_update`` — a
-       Pallas kernel on TPU with the table in VMEM, the jnp oracle
-       elsewhere; root folds are skipped at runtime on hitless steps);
+       *records* on a virtual id space (``kernels.ref.arena_build_ref``,
+       one XLA computation on every platform; root folds are skipped at
+       runtime on hitless steps);
     2. assigns real node ids with ONE chunk-level exclusive cumsum of the
        record-validity mask (the bump allocator, batched) and translates
        every virtual reference in one vectorized pass — overflowers clamp
@@ -542,6 +559,11 @@ def arena_scan_block(tables: ArenaTables, arena: dict,
     measured slower on CPU XLA (the step is bandwidth-bound there), kept
     as a knob for accelerator backends.
 
+    Records are dense (``M`` slots per event and lane, ``M`` growing with
+    ring × states), so when a chunk's ``(T, B, M)`` record array would
+    pass :data:`ARENA_RECORD_BYTES` the lanes run in groups under
+    ``lax.map`` (:func:`arena_lane_group`).
+
     The slot layout replays the reference fold's allocation order exactly,
     so non-overflowing lanes produce bit-identical node stores — asserted
     by tests/test_arena_block.py.
@@ -549,13 +571,54 @@ def arena_scan_block(tables: ArenaTables, arena: dict,
     ``expire`` (optional, (T, B, W) bool): precomputed time-window
     eviction masks — same contract as :func:`arena_scan` (DESIGN.md §9).
     They are closed-form in the absolute event index, so segmented
-    execution and the Pallas kernel consume them as one more streamed
-    operand.  ``consume`` (optional, (T, B, S) bool): CONSUME BY ANY
+    execution consumes them as one more streamed operand.  ``consume`` (optional, (T, B, S) bool): CONSUME BY ANY
     clear masks — same contract as :func:`arena_scan`; clearing allocates
     nothing, so the record layout, the chunk-level cumsum and the decoded
     ``kind``/``pos``/``max_start`` are all untouched.
     """
-    from ..kernels import ops
+    T, B = class_ids.shape
+    W = arena["cell"].shape[1]
+    cap = arena["kind"].shape[1] - 1
+    G = arena_lane_group(T, B, _block_layout(tables, W, epsilon, cap).M)
+    kw = dict(epsilon=epsilon, n_seg=n_seg)
+    start = jnp.broadcast_to(jnp.asarray(start, jnp.int32), (B,))
+    valid = jnp.broadcast_to(jnp.asarray(valid, jnp.int32), (B,))
+    if G == B:
+        return _scan_block_lanes(tables, arena, class_ids, gpos, start,
+                                 valid, hits, expire=expire,
+                                 consume=consume, **kw)
+    # lanes are independent (own cells and node store): run them in groups
+    # so the (T, G, M) records stay within budget
+    n = B // G
+
+    def lanes_first(x):            # (B, ...) → (n, G, ...)
+        return x.reshape((n, G) + x.shape[1:])
+
+    def steps_first(x):            # (T, B, ...) → (n, T, G, ...)
+        x = jnp.asarray(x)
+        return jnp.moveaxis(x.reshape((T, n, G) + x.shape[2:]), 1, 0)
+
+    xs = ({k: lanes_first(v) for k, v in arena.items()},
+          steps_first(class_ids), steps_first(gpos), lanes_first(start),
+          lanes_first(valid), steps_first(hits),
+          None if expire is None else steps_first(expire),
+          None if consume is None else steps_first(consume))
+
+    def group(x):
+        ar, cl, gp, st, va, hi, ex, co = x
+        return _scan_block_lanes(tables, ar, cl, gp, st, va, hi, expire=ex,
+                                 consume=co, **kw)
+
+    arena_g, roots_g = jax.lax.map(group, xs)
+    arena = {k: v.reshape((B,) + v.shape[2:]) for k, v in arena_g.items()}
+    roots = jnp.moveaxis(roots_g, 0, 1).reshape((T, B) + roots_g.shape[3:])
+    return arena, roots
+
+
+def _scan_block_lanes(tables: ArenaTables, arena: dict, class_ids, gpos,
+                      start, valid, hits, *, epsilon: int, expire, consume,
+                      n_seg: int) -> Tuple[dict, jnp.ndarray]:
+    """:func:`arena_scan_block` over one group of lanes."""
     T, B = class_ids.shape
     W = arena["cell"].shape[1]
     cap = arena["kind"].shape[1] - 1
@@ -563,8 +626,6 @@ def arena_scan_block(tables: ArenaTables, arena: dict,
     ptab = _ptab(tables)
     M = lay.M
     Q = lay.Q
-    start = jnp.broadcast_to(jnp.asarray(start, jnp.int32), (B,))
-    valid = jnp.broadcast_to(jnp.asarray(valid, jnp.int32), (B,))
     gpos = jnp.asarray(gpos, jnp.int32)
 
     # -- chunk-start cell attributes, gathered from the node store ---------
@@ -579,11 +640,10 @@ def arena_scan_block(tables: ArenaTables, arena: dict,
 
     # -- 1+2. builder scan: cell-table recurrence + record emission --------
     cells_T, rec_valid, rec_left, rec_right, roots_v = \
-        ops.arena_block_update(
+        kref.arena_build_ref(
             cells0, class_ids, hits, start, valid, lay=lay, ptab=ptab,
             finals_sq=tables.finals_sq, n_seg=n_seg, expire=expire,
-            consume=consume, use_pallas=use_pallas, interpret=interpret,
-            b_tile=b_tile)
+            consume=consume)
 
     # -- 3+4 run under one chunk-level allocation gate: a chunk with zero
     # allocations (every step dead — idle fleet engines, service tail
@@ -697,8 +757,7 @@ def window_expire_masks(window: "wkern.DeviceWindow", ts_ring0, event_ts,
 
 def run_arena_scan(atables: ArenaTables, arena: dict, trace, gpos, start,
                    valid, hits, *, epsilon: int, expire=None, consume=None,
-                   arena_impl: str = "block",
-                   use_pallas: bool = False, b_tile: int = 8):
+                   arena_impl: str = "block"):
     """Dispatch one arena chunk to the selected implementation.
 
     ``arena_impl``: ``"block"`` (vectorized allocation + batched scatters,
@@ -713,15 +772,14 @@ def run_arena_scan(atables: ArenaTables, arena: dict, trace, gpos, start,
         return arena_scan(atables, arena, trace, gpos, start, valid, hits,
                           epsilon=epsilon, expire=expire, consume=consume)
     return arena_scan_block(atables, arena, trace, gpos, start, valid, hits,
-                            epsilon=epsilon, expire=expire, consume=consume,
-                            use_pallas=use_pallas, b_tile=b_tile)
+                            epsilon=epsilon, expire=expire, consume=consume)
 
 
 def scan_chunk(atables: ArenaTables, arena: dict, attrs, state, *,
                specs, class_of, class_ind, m_all, finals_q, init_mask,
-               window: "wkern.DeviceWindow", start, gbase, impl,
-               use_pallas, b_tile, arena_impl: str = "block",
-               event_ts=None, latest_q=None, consume_sq=None):
+               window: "wkern.DeviceWindow", start, gbase, route,
+               arena_impl: str = "block", event_ts=None, latest_q=None,
+               consume_sq=None):
     """One chunk through the fused pipeline + arena at a common offset.
 
     The whole-batch case: every lane advances by the same T events from
@@ -736,7 +794,8 @@ def scan_chunk(atables: ArenaTables, arena: dict, attrs, state, *,
     ``repro.core.query.resolve_semantics``): both feed the counting
     kernels, and ``consume_sq`` additionally derives the arena's
     per-step cell-clear masks from the emitted matches, so the node
-    store mirrors the count ring's consumption exactly.
+    store mirrors the count ring's consumption exactly.  ``route`` is the
+    caller's recorded scan :class:`~repro.kernels.ops.Route`.
     Returns ``(matches, state', arena', roots)``.
     """
     from ..kernels import ops
@@ -744,8 +803,7 @@ def scan_chunk(atables: ArenaTables, arena: dict, attrs, state, *,
     matches, state, trace = ops.cer_pipeline(
         attrs, specs, class_of, class_ind, m_all, finals_q, state,
         init_mask=init_mask, window=window, event_ts=event_ts,
-        start_pos=start, impl=impl,
-        use_pallas=use_pallas, b_tile=b_tile, return_trace=True,
+        start_pos=start, route=route, return_trace=True,
         latest_q=latest_q, consume_sq=consume_sq)
     T, B = trace.shape
     gpos = jnp.broadcast_to(
@@ -763,12 +821,13 @@ def scan_chunk(atables: ArenaTables, arena: dict, attrs, state, *,
     consume = (jnp.einsum(
         "tbq,qs->tbs", hits.astype(jnp.float32),
         jnp.asarray(consume_sq, jnp.float32)[:atables.num_queries,
-                                             :atables.num_states]) > 0.5
+                                             :atables.num_states],
+        precision=jax.lax.Precision.HIGHEST) > 0.5
         if consume_sq is not None else None)
     arena, roots = run_arena_scan(
         atables, arena, trace, gpos, start_b, valid_b, hits,
         epsilon=window.epsilon, expire=expire, consume=consume,
-        arena_impl=arena_impl, use_pallas=use_pallas, b_tile=b_tile)
+        arena_impl=arena_impl)
     return matches, state, arena, roots
 
 
@@ -847,15 +906,18 @@ def run_enumerate(engine, streams, start_pos: int = 0,
     latest_q = getattr(tbl, "latest_q", None)
     consume_sq = getattr(tbl, "consume_sq", None)
 
+    from .engine import plan_oneshot
+    from ..kernels.ops import ARENA_ROUTE
+    route = plan_oneshot(engine, attrs.shape, trace=True)
+    engine.routes["arena"] = ARENA_ROUTE
+
     def step(attrs, state, arena, start, ts):
         # one-shot: absolute positions and ring offsets coincide
         matches, _, arena, roots = scan_chunk(
             atables, arena, attrs, state, specs=engine.encoder.specs,
             class_of=tbl.class_of, class_ind=tbl.class_ind,
             m_all=tbl.m_all, finals_q=finals_q, init_mask=tbl.init_mask,
-            window=engine.window, start=start, gbase=start,
-            impl=engine.impl, use_pallas=engine.use_pallas,
-            b_tile=engine.b_tile,
+            window=engine.window, start=start, gbase=start, route=route,
             arena_impl=getattr(engine, "arena_impl", "block"),
             event_ts=ts, latest_q=latest_q, consume_sq=consume_sq)
         return matches, arena, roots
@@ -863,10 +925,10 @@ def run_enumerate(engine, streams, start_pos: int = 0,
     cache = getattr(engine, "_enum_jit", None)
     if cache is None:
         cache = engine._enum_jit = {}
-    jitted = cache.get(getattr(engine, "arena_impl", "block"))
+    key = (getattr(engine, "arena_impl", "block"), route)
+    jitted = cache.get(key)
     if jitted is None:
-        jitted = cache[getattr(engine, "arena_impl", "block")] = \
-            jax.jit(step)
+        jitted = cache[key] = jax.jit(step)
     T, B = attrs.shape[:2]
     state = engine.init_state(B)
     arena = init_arena(B, arena_capacity, engine.ring, atables.num_states)

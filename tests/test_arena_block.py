@@ -8,8 +8,8 @@ entry, the cell tables, bump pointers, overflow flags and emitted roots are
 compared verbatim against :func:`repro.vector.tecs_arena.arena_scan` (the
 retained per-event fold).  Sweeps cover whole streams, chunk-straddling
 feeds, ragged per-lane offsets/valid-counts (the PARTITION BY contract),
-packed multi-query tables, the segmented scan, and the Pallas kernel in
-interpret mode; the overflow latch is exercised under block allocation.
+packed multi-query tables, the segmented scan, and lane groups under a
+record budget; the overflow latch is exercised under block allocation.
 """
 import random
 
@@ -17,7 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _hyp import given, settings, st
+from hypothesis import given, settings, strategies as st
 from repro.core.engine import Engine, WindowSpec
 from repro.core.events import Event
 from repro.core import compile_query
@@ -51,7 +51,7 @@ def trace_of(engine, attrs, state, eps, start_pos=0, valid=None):
     return ops.cer_pipeline(
         attrs, engine.encoder.specs, t.class_of, t.class_ind, t.m_all,
         finals_q, state, init_mask=t.init_mask, epsilon=eps,
-        start_pos=start_pos, valid_counts=valid, impl="ref",
+        start_pos=start_pos, valid_counts=valid, route=ops.REF_ROUTE,
         return_trace=True)
 
 
@@ -144,18 +144,18 @@ def test_segmented_scan_store_parity():
     run_both(ve, make_streams(13, 2, 128), eps=3, chunk=64, n_seg=4)
 
 
-def test_pallas_kernel_store_parity():
-    """The Pallas builder kernel (interpret mode) runs the same step as
-    the jnp oracle — stores must be bit-identical end to end."""
+@pytest.mark.parametrize("lanes", [1, 2])
+def test_lane_groups_store_parity(monkeypatch, lanes):
+    """A record budget below the batch runs the builder in lane groups
+    (lax.map); stores must stay bit-identical to the fold."""
     ve = VectorEngine(QUERIES[1], epsilon=6, use_pallas=False)
-    run_both(ve, make_streams(3, 2, 48), eps=6, chunk=16,
-             use_pallas=True, interpret=True, b_tile=2)
-
-
-def test_pallas_kernel_segmented_store_parity():
-    ve = VectorEngine(QUERIES[0], epsilon=3, use_pallas=False)
-    run_both(ve, make_streams(4, 2, 64), eps=3, chunk=64, n_seg=2,
-             use_pallas=True, interpret=True, b_tile=2)
+    T, chunk = 48, 16
+    at = ve.arena_tables()
+    M = tecs_arena._block_layout(at, ve.ring, 6, 1 << 12).M
+    monkeypatch.setattr(tecs_arena, "ARENA_RECORD_BYTES",
+                        4 * chunk * M * lanes)
+    assert tecs_arena.arena_lane_group(chunk, 4, M) == lanes
+    run_both(ve, make_streams(3, 4, T), eps=6, chunk=chunk)
 
 
 def test_overflow_latches_under_block_allocation():

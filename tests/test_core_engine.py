@@ -2,7 +2,7 @@
 import random
 
 import pytest
-from _hyp import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import Event, compile_query
 from repro.core.cel import complex_events as oracle_ce

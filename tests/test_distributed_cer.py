@@ -59,7 +59,7 @@ def test_sharded_pipeline_matches_local_fused():
             use_pallas=True)
     m_loc, c_loc = ops.cer_pipeline(
         attrs, specs, class_of, class_ind, M, finals_q, c0,
-        init_mask=init_mask, epsilon=eps, start_pos=3, impl="ref")
+        init_mask=init_mask, epsilon=eps, start_pos=3, route=ops.REF_ROUTE)
     np.testing.assert_allclose(np.asarray(m_sh), np.asarray(m_loc))
     np.testing.assert_allclose(np.asarray(c_sh), np.asarray(c_loc))
 

@@ -14,16 +14,15 @@ Two invariants guard PR 10's perf work:
   Python DFS, Algorithm 2 as written), for every compiled selection
   strategy × window kind.
 
-Property-based variants run when hypothesis is installed (tests/_hyp.py
-shim); the seeded sweeps cover the same ground deterministically either
-way.
+Property-based variants sit next to seeded sweeps that cover the same
+ground deterministically.
 """
 import random
 
 import numpy as np
 import pytest
 
-from _hyp import given, settings, st
+from hypothesis import given, settings, strategies as st
 from repro.core import Event
 from repro.runtime.fleet import QueryFleet
 from repro.vector import StreamingVectorEngine, VectorEngine

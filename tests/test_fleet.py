@@ -19,7 +19,7 @@ import textwrap
 import numpy as np
 import pytest
 
-from _hyp import given, settings, st
+from hypothesis import given, settings, strategies as st
 from repro.core.events import Event
 from repro.runtime.fleet import QueryFleet
 from repro.vector.multiquery import (MultiQueryEngine, PackingInvariantError,

@@ -40,6 +40,18 @@ def pipeline_args(specs, class_of, M, finals_q, *, num_classes):
             jnp.asarray(M), jnp.asarray(finals_q))
 
 
+def pipe(attrs, specs, class_of, class_ind, M, finals_q, c0, *, impl,
+         return_trace=False, **kw):
+    """``ops.cer_pipeline`` on the route ``impl`` plans for these shapes."""
+    T, B, A = attrs.shape
+    route = ops.plan_pipeline(
+        T=T, B=B, A=A, W=c0.shape[1], S=M.shape[1], NC=M.shape[0],
+        NQ=finals_q.shape[0], V=class_ind.shape[0], trace=return_trace,
+        impl=impl)
+    return ops.cer_pipeline(attrs, specs, class_of, class_ind, M, finals_q,
+                            c0, route=route, return_trace=return_trace, **kw)
+
+
 # ---------------------------------------------------------------------------
 # fused kernel parity vs oracle
 # ---------------------------------------------------------------------------
@@ -54,9 +66,9 @@ def test_fused_pipeline_matches_ref(S, C, k, B, T, A, eps):
     c0 = jnp.zeros((B, ops.ring_size(eps), S), jnp.float32)
     args = pipeline_args(specs, class_of, M, finals[None, :], num_classes=C)
     kw = dict(init_mask=jnp.asarray(init), epsilon=eps)
-    m_f, c_f = ops.cer_pipeline(attrs, specs, *args, c0, **kw, impl="fused")
-    m_u, c_u = ops.cer_pipeline(attrs, specs, *args, c0, **kw, impl="unfused")
-    m_r, c_r = ops.cer_pipeline(attrs, specs, *args, c0, **kw, impl="ref")
+    m_f, c_f = pipe(attrs, specs, *args, c0, **kw, impl="fused")
+    m_u, c_u = pipe(attrs, specs, *args, c0, **kw, impl="unfused")
+    m_r, c_r = pipe(attrs, specs, *args, c0, **kw, impl="ref")
     np.testing.assert_array_equal(np.asarray(m_f), np.asarray(m_r))
     np.testing.assert_array_equal(np.asarray(m_u), np.asarray(m_r))
     np.testing.assert_array_equal(np.asarray(c_f), np.asarray(c_r))
@@ -75,10 +87,10 @@ def test_fused_pipeline_class_trace_matches_ref(S, C, k, B, T, A, eps):
     c0 = jnp.zeros((B, ops.ring_size(eps), S), jnp.float32)
     args = pipeline_args(specs, class_of, M, finals[None, :], num_classes=C)
     kw = dict(init_mask=jnp.asarray(init), epsilon=eps)
-    m_f, c_f, tr_f = ops.cer_pipeline(attrs, specs, *args, c0, **kw,
+    m_f, c_f, tr_f = pipe(attrs, specs, *args, c0, **kw,
                                       impl="fused", return_trace=True)
-    m_2, c_2 = ops.cer_pipeline(attrs, specs, *args, c0, **kw, impl="fused")
-    m_r, c_r, tr_r = ops.cer_pipeline(attrs, specs, *args, c0, **kw,
+    m_2, c_2 = pipe(attrs, specs, *args, c0, **kw, impl="fused")
+    m_r, c_r, tr_r = pipe(attrs, specs, *args, c0, **kw,
                                       impl="ref", return_trace=True)
     assert tr_f.dtype == jnp.int32
     np.testing.assert_array_equal(np.asarray(tr_f), np.asarray(tr_r))
@@ -102,12 +114,12 @@ def test_fused_pipeline_dynamic_start_pos_traced():
     @jax.jit
     def step(a, c, sp):
         traces.append(1)
-        return ops.cer_pipeline(a, specs, *args, c, **kw,
+        return pipe(a, specs, *args, c, **kw,
                                 start_pos=sp, impl="fused")
 
     for sp in (0, 5, 17):
         m_jit, _ = step(attrs, c0, jnp.asarray(sp, jnp.int32))
-        m_ref, _ = ops.cer_pipeline(attrs, specs, *args, c0, **kw,
+        m_ref, _ = pipe(attrs, specs, *args, c0, **kw,
                                     start_pos=sp, impl="ref")
         np.testing.assert_array_equal(np.asarray(m_jit), np.asarray(m_ref))
     assert len(traces) == 1  # dynamic start_pos → no per-offset recompile
@@ -124,12 +136,12 @@ def test_fused_chunked_equals_whole_stream(split):
     c0 = jnp.zeros((B, ops.ring_size(eps), S), jnp.float32)
     args = pipeline_args(specs, class_of, M, finals[None, :], num_classes=C)
     kw = dict(init_mask=jnp.asarray(init), epsilon=eps)
-    m_whole, _ = ops.cer_pipeline(jnp.asarray(attrs), specs, *args, c0, **kw,
+    m_whole, _ = pipe(jnp.asarray(attrs), specs, *args, c0, **kw,
                                   impl="ref")
     for impl in ("fused", "unfused", "ref"):
-        m1, c_mid = ops.cer_pipeline(jnp.asarray(attrs[:split]), specs,
+        m1, c_mid = pipe(jnp.asarray(attrs[:split]), specs,
                                      *args, c0, **kw, impl=impl)
-        m2, _ = ops.cer_pipeline(jnp.asarray(attrs[split:]), specs, *args,
+        m2, _ = pipe(jnp.asarray(attrs[split:]), specs, *args,
                                  c_mid, **kw, start_pos=split, impl=impl)
         np.testing.assert_array_equal(
             np.concatenate([np.asarray(m1), np.asarray(m2)]),

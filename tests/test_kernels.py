@@ -3,7 +3,7 @@ import numpy as np
 import pytest
 import jax
 import jax.numpy as jnp
-from _hyp import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.kernels import ops, ref
 from repro.kernels.ref import OP_EQ, OP_GE, OP_GT, OP_LE, OP_LT, OP_NE

@@ -22,14 +22,12 @@ SCRIPT = textwrap.dedent("""
     x = jax.random.normal(jax.random.PRNGKey(1), (4, 1, cfg.d_model),
                           jnp.float32)
     y_ref, aux_ref = moe_mod._moe_global(p, cfg, x)
-    # version-compat mesh path: axis_types / set_mesh / get_abstract_mesh
-    # only exist on newer jax — route through repro.jaxcompat, and hand the
-    # concrete mesh to the stationary path directly (it only reads
-    # mesh.shape / mesh.axis_names, which both mesh flavours provide).
-    from repro.jaxcompat import current_mesh, make_mesh, use_mesh
-    mesh = make_mesh((2, 2), ("data", "model"))
-    with use_mesh(mesh):
-        sm_mesh = current_mesh() or mesh
+    # hand the ambient abstract mesh to the stationary path (it only reads
+    # mesh.shape / mesh.axis_names)
+    mesh = jax.make_mesh((2, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
+    with jax.set_mesh(mesh):
+        sm_mesh = jax.sharding.get_abstract_mesh()
         y_st, aux_st = jax.jit(
             lambda pp, xx: moe_mod._moe_decode_stationary(
                 pp, cfg, xx, sm_mesh))(p, x)

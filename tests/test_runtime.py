@@ -105,12 +105,11 @@ def test_elastic_restore_resharded(tmp_path):
     """A checkpoint restores onto a different mesh topology."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from repro.jaxcompat import make_mesh
-
     ckpt = CheckpointManager(str(tmp_path))
     tree = {"w": jnp.arange(16.0).reshape(4, 4)}
     ckpt.save(5, tree)
-    mesh = make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     shardings = {"w": NamedSharding(mesh, P("data", None))}
     restored, _ = restore_resharded(ckpt, tree, shardings)
     np.testing.assert_array_equal(np.asarray(restored["w"]),

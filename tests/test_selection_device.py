@@ -15,7 +15,7 @@ import random
 import numpy as np
 import pytest
 
-from _hyp import given, settings, st
+from hypothesis import given, settings, strategies as st
 from repro.core import Event, compile_query
 from repro.core.engine import Engine
 from repro.core.partition import PartitionedEngine

@@ -9,7 +9,7 @@ import random
 import time
 
 import pytest
-from _hyp import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import Event, compile_query
 from repro.core.engine import Engine, WindowSpec

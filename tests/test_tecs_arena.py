@@ -5,16 +5,15 @@ the stronger property the arena buys us: the *enumerated complex events*
 (start, end, data) coming out of the device arena are bit-identical to the
 host Algorithm 1 + Algorithm 2 output — on randomized query × stream sweeps,
 across chunk boundaries, under PARTITION BY routing with NULL keys, and for
-packed multi-query tables.  Property-based variants run when hypothesis is
-installed (tests/_hyp.py shim); the seeded sweeps below cover the same
-ground deterministically either way.
+packed multi-query tables.  Property-based variants sit next to seeded
+sweeps that cover the same ground deterministically.
 """
 import random
 
 import numpy as np
 import pytest
 
-from _hyp import given, settings, st
+from hypothesis import given, settings, strategies as st
 from repro.core import compile_query
 from repro.core.engine import Engine, WindowSpec
 from repro.core.events import Event
@@ -190,7 +189,7 @@ def test_arena_overflow_latches_in_scan():
     m, _, trace = ops.cer_pipeline(
         attrs, ve.encoder.specs, tbl.class_of, tbl.class_ind, tbl.m_all,
         tbl.finals[None, :], ve.init_state(B), init_mask=tbl.init_mask,
-        epsilon=eps, start_pos=0, impl="ref", return_trace=True)
+        epsilon=eps, start_pos=0, route=ops.REF_ROUTE, return_trace=True)
     tables = ve.arena_tables()
     arena = tecs_arena.init_arena(B, 32, ve.ring, tables.num_states)
     gpos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[:, None], (T, B))

@@ -345,8 +345,7 @@ def test_time_window_arena_block_equals_fold_bitwise():
             class_of=tbl.class_of, class_ind=tbl.class_ind,
             m_all=tbl.m_all, finals_q=tbl.finals[None, :],
             init_mask=tbl.init_mask, window=ve.window, start=0, gbase=0,
-            impl=ve.impl, use_pallas=False, b_tile=8,
-            arena_impl=arena_impl, event_ts=t))
+            route=ops.REF_ROUTE, arena_impl=arena_impl, event_ts=t))
         m, _, arena, roots = step(attrs, state, arena, ts)
         return np.asarray(m), arena, np.asarray(roots)
 
