@@ -452,96 +452,98 @@ def cer_pipeline(attrs: jnp.ndarray,
     same form.  Time windows route to the fused Pallas kernel or the
     fused-XLA computation (the legacy unfused kernels are count-only).
     """
-    if window is None:
-        if epsilon is None:
-            raise ValueError("cer_pipeline needs epsilon= or window=")
-        window = DeviceWindow.events(epsilon)
-    timed = window.is_time
-    epsilon = window.epsilon
-    if timed and event_ts is None:
-        raise ValueError("time windows need the event_ts (T, B) operand")
-    T, B, A = attrs.shape
-    if timed:
-        event_ts = jnp.asarray(event_ts, jnp.float32)
-        if event_ts.shape != (T, B):
-            # (T, B) like attrs — a transposed operand would fail deep in
-            # the kernel, or silently mis-evict when T == B
-            raise ValueError(f"event_ts must be (T, B) = ({T}, {B}) like "
-                             f"attrs, got {event_ts.shape}")
-    NC, S, _ = m_all.shape
-    c_ring = c0["C"] if timed else c0
-    NQ = finals_q.shape[0]
+    with jax.named_scope("scan"):
+        if window is None:
+            if epsilon is None:
+                raise ValueError("cer_pipeline needs epsilon= or window=")
+            window = DeviceWindow.events(epsilon)
+        timed = window.is_time
+        epsilon = window.epsilon
+        if timed and event_ts is None:
+            raise ValueError("time windows need the event_ts (T, B) operand")
+        T, B, A = attrs.shape
+        if timed:
+            event_ts = jnp.asarray(event_ts, jnp.float32)
+            if event_ts.shape != (T, B):
+                # (T, B) like attrs — a transposed operand would fail deep in
+                # the kernel, or silently mis-evict when T == B
+                raise ValueError(f"event_ts must be (T, B) = ({T}, {B}) like "
+                                 f"attrs, got {event_ts.shape}")
+        NC, S, _ = m_all.shape
+        c_ring = c0["C"] if timed else c0
+        NQ = finals_q.shape[0]
 
-    if route.path == "xla":
-        return _pipeline_xla(attrs, specs, class_of, m_all, finals_q, c0,
-                             init_mask, epsilon, start_pos, valid_counts,
-                             return_trace, window=window, event_ts=event_ts,
-                             latest_q=latest_q, consume_sq=consume_sq)
+        if route.path == "xla":
+            return _pipeline_xla(attrs, specs, class_of, m_all, finals_q, c0,
+                                 init_mask, epsilon, start_pos, valid_counts,
+                                 return_trace, window=window,
+                                 event_ts=event_ts,
+                                 latest_q=latest_q, consume_sq=consume_sq)
 
-    if route.path == "unfused":
-        # legacy 3-dispatch path: bits kernel → gather → scan kernel
-        bits = bitvector(attrs.reshape(T * B, A), specs,
-                         interpret=route.interpret)
-        class_ids = class_of[bits].reshape(T, B)
-        matches, c_fin = cea_scan_multi(
-            class_ids, m_all, finals_q, c0, init_mask=init_mask,
-            epsilon=epsilon, start_pos=start_pos,
-            interpret=route.interpret, b_tile=route.b_tile)
+        if route.path == "unfused":
+            # legacy 3-dispatch path: bits kernel → gather → scan kernel
+            bits = bitvector(attrs.reshape(T * B, A), specs,
+                             interpret=route.interpret)
+            class_ids = class_of[bits].reshape(T, B)
+            matches, c_fin = cea_scan_multi(
+                class_ids, m_all, finals_q, c0, init_mask=init_mask,
+                epsilon=epsilon, start_pos=start_pos,
+                interpret=route.interpret, b_tile=route.b_tile)
+            if return_trace:
+                return matches, c_fin, class_ids.astype(jnp.int32)
+            return matches, c_fin
+
+        # --- the fused Pallas kernel -----------------------------------------
+        b_tile, t_tile = route.b_tile, route.t_tile
+        Sp = _pad_to(S, 128)
+        NCp = _pad_to(NC, 8)
+        NQp = _pad_to(NQ, 8)
+        Bp = _pad_to(B, b_tile)
+        Tp = _pad_to(T, t_tile)                      # padded steps are dead
+        a_pad = jnp.pad(jnp.transpose(attrs, (2, 1, 0)),
+                        ((0, 0), (0, Bp - B), (0, Tp - T)))    # (A, Bp, Tp)
+        ind_pad = jnp.pad(class_ind, ((0, 0), (0, NCp - NC)))
+        m_flat = jnp.pad(m_all, ((0, NCp - NC), (0, Sp - S), (0, Sp - S))
+                         ).reshape(NCp, Sp * Sp)
+        f_pad = jnp.pad(finals_q.astype(jnp.float32),
+                        ((0, NQp - NQ), (0, Sp - S)))
+        i_pad = jnp.pad(init_mask.astype(jnp.float32), (0, Sp - S))[None, :]
+        c_pad = jnp.pad(c_ring, ((0, Bp - B), (0, 0), (0, Sp - S)))
+        start_lanes = _lane_arr(start_pos, B, Bp, fill=0)
+        valid_lanes = _lane_arr(T if valid_counts is None else valid_counts,
+                                B, Bp, fill=0)       # padded lanes are dead
+        time_kw = {}
+        if timed:
+            time_kw = dict(
+                time_size=float(window.size),
+                event_ts=jnp.pad(event_ts.T, ((0, Bp - B), (0, Tp - T))),
+                ts_ring0=jnp.pad(c0["ts"], ((0, Bp - B), (0, 0)),
+                                 constant_values=TS_EMPTY),
+                ovf0=jnp.pad(c0["ovf"].astype(jnp.int32)[:, None],
+                             ((0, Bp - B), (0, 0))))
+        sem_kw = {}
+        if latest_q is not None:
+            sem_kw["latest_q"] = jnp.pad(
+                jnp.asarray(latest_q, jnp.float32), (0, NQp - NQ))[None, :]
+        if consume_sq is not None:
+            sem_kw["consume_sq"] = jnp.pad(
+                jnp.asarray(consume_sq, jnp.float32),
+                ((0, NQp - NQ), (0, Sp - S)))
+
+        res = fused_scan_pallas(
+            a_pad, ind_pad, m_flat, f_pad, i_pad, c_pad, start_lanes,
+            valid_lanes, specs=tuple(specs), epsilon=epsilon, b_tile=b_tile,
+            t_tile=t_tile, interpret=route.interpret, emit_trace=return_trace,
+            **time_kw, **sem_kw)
+        matches, c_fin = res[0], res[1]
+        c_out = c_fin[:B, :, :S]
+        if timed:
+            c_out = {"C": c_out, "ts": res[2][:B],
+                     "ovf": res[3][:B, 0].astype(bool)}
+        out = jnp.transpose(matches[:NQ, :B, :T], (2, 1, 0)), c_out
         if return_trace:
-            return matches, c_fin, class_ids.astype(jnp.int32)
-        return matches, c_fin
-
-    # --- the fused Pallas kernel ---------------------------------------------
-    b_tile, t_tile = route.b_tile, route.t_tile
-    Sp = _pad_to(S, 128)
-    NCp = _pad_to(NC, 8)
-    NQp = _pad_to(NQ, 8)
-    Bp = _pad_to(B, b_tile)
-    Tp = _pad_to(T, t_tile)                      # padded steps are dead
-    a_pad = jnp.pad(jnp.transpose(attrs, (2, 1, 0)),
-                    ((0, 0), (0, Bp - B), (0, Tp - T)))        # (A, Bp, Tp)
-    ind_pad = jnp.pad(class_ind, ((0, 0), (0, NCp - NC)))
-    m_flat = jnp.pad(m_all, ((0, NCp - NC), (0, Sp - S), (0, Sp - S))
-                     ).reshape(NCp, Sp * Sp)
-    f_pad = jnp.pad(finals_q.astype(jnp.float32),
-                    ((0, NQp - NQ), (0, Sp - S)))
-    i_pad = jnp.pad(init_mask.astype(jnp.float32), (0, Sp - S))[None, :]
-    c_pad = jnp.pad(c_ring, ((0, Bp - B), (0, 0), (0, Sp - S)))
-    start_lanes = _lane_arr(start_pos, B, Bp, fill=0)
-    valid_lanes = _lane_arr(T if valid_counts is None else valid_counts,
-                            B, Bp, fill=0)       # padded lanes are dead
-    time_kw = {}
-    if timed:
-        time_kw = dict(
-            time_size=float(window.size),
-            event_ts=jnp.pad(event_ts.T, ((0, Bp - B), (0, Tp - T))),
-            ts_ring0=jnp.pad(c0["ts"], ((0, Bp - B), (0, 0)),
-                             constant_values=TS_EMPTY),
-            ovf0=jnp.pad(c0["ovf"].astype(jnp.int32)[:, None],
-                         ((0, Bp - B), (0, 0))))
-    sem_kw = {}
-    if latest_q is not None:
-        sem_kw["latest_q"] = jnp.pad(
-            jnp.asarray(latest_q, jnp.float32), (0, NQp - NQ))[None, :]
-    if consume_sq is not None:
-        sem_kw["consume_sq"] = jnp.pad(
-            jnp.asarray(consume_sq, jnp.float32),
-            ((0, NQp - NQ), (0, Sp - S)))
-
-    res = fused_scan_pallas(
-        a_pad, ind_pad, m_flat, f_pad, i_pad, c_pad, start_lanes,
-        valid_lanes, specs=tuple(specs), epsilon=epsilon, b_tile=b_tile,
-        t_tile=t_tile, interpret=route.interpret, emit_trace=return_trace,
-        **time_kw, **sem_kw)
-    matches, c_fin = res[0], res[1]
-    c_out = c_fin[:B, :, :S]
-    if timed:
-        c_out = {"C": c_out, "ts": res[2][:B],
-                 "ovf": res[3][:B, 0].astype(bool)}
-    out = jnp.transpose(matches[:NQ, :B, :T], (2, 1, 0)), c_out
-    if return_trace:
-        return out + (res[-1][:B, :T].T,)
-    return out
+            return out + (res[-1][:B, :T].T,)
+        return out
 
 
 def _pipeline_xla(attrs, specs, class_of, m_all, finals_q, c0, init_mask,

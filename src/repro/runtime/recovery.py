@@ -41,6 +41,7 @@ import os
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..checkpoint.manager import CheckpointManager
 from ..kernels.window import WindowOverflowError
@@ -184,7 +185,8 @@ class RecoveringStreamRunner:
     def __init__(self, engine, directory: str, *, every: int = 8,
                  keep: int = 3, policy: Optional[RetryPolicy] = None,
                  heartbeat_timeout: Optional[float] = None,
-                 feed_method: str = "feed", blocking_saves: bool = True):
+                 feed_method: str = "feed", blocking_saves: bool = True,
+                 metrics=None):
         if every < 1:
             raise ValueError(f"checkpoint interval must be ≥ 1, got {every}")
         self.engine = engine
@@ -194,6 +196,9 @@ class RecoveringStreamRunner:
                        else DEFAULT_STEP_POLICY)
         self.feed_method = feed_method
         self.blocking_saves = blocking_saves
+        #: counts ``checkpoints`` and ``checkpoint_bytes`` when given (the
+        #: service's ``ServiceMetrics``)
+        self.metrics = metrics
         os.makedirs(directory, exist_ok=True)
         self.manager = CheckpointManager(
             os.path.join(directory, "ckpt"), keep=keep)
@@ -279,7 +284,8 @@ class RecoveringStreamRunner:
             self._check_replay(idx, counts, hits)
             emitted = False
         else:
-            self.log.append(idx, counts, hits)
+            with TraceAnnotation("service.log", chunk=idx):
+                self.log.append(idx, counts, hits)
             emitted = True
         if self.chunk_index % self.every == 0:
             self.checkpoint()
@@ -303,10 +309,18 @@ class RecoveringStreamRunner:
     def checkpoint(self) -> None:
         """Snapshot the engine now (log-before-checkpoint ordering: every
         record covering the snapshot is already flushed)."""
-        snap = self.engine.snapshot()
-        extra = dict(snap["meta"], chunk=self.chunk_index)
-        self.manager.save(self.chunk_index, snap["arrays"],
-                          blocking=self.blocking_saves, extra=extra)
+        # chunk: the last chunk the snapshot covers
+        with TraceAnnotation("service.checkpoint",
+                             chunk=self.chunk_index - 1) as span:
+            snap = self.engine.snapshot()
+            extra = dict(snap["meta"], chunk=self.chunk_index)
+            self.manager.save(self.chunk_index, snap["arrays"],
+                              blocking=self.blocking_saves, extra=extra)
+            nbytes = sum(int(a.nbytes) for a in snap["arrays"].values())
+            span.set_metadata(bytes=nbytes)
+        if self.metrics is not None:
+            self.metrics.checkpoints += 1
+            self.metrics.checkpoint_bytes += nbytes
 
     def close(self) -> None:
         if self.monitor is not None:

@@ -59,6 +59,7 @@ dropped, and the replayed chunk would diverge from its durable record.
 """
 from __future__ import annotations
 
+import gc
 import json
 import os
 import queue
@@ -69,6 +70,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from ..core.events import Event
 from ..core.partition import partition_key
@@ -410,14 +412,44 @@ class ServiceMetrics:
     overflows: int = 0
     regrows: int = 0
     queue_peak: int = 0
+    checkpoints: int = 0
+    checkpoint_bytes: int = 0        # host bytes of the snapshots taken
     chunk_latency_s: List[float] = field(default_factory=list)
 
-    def latency_percentiles(self) -> Dict[str, float]:
-        if not self.chunk_latency_s:
-            return {"p50": 0.0, "p99": 0.0}
-        lat = np.asarray(self.chunk_latency_s)
-        return {"p50": float(np.percentile(lat, 50)),
-                "p99": float(np.percentile(lat, 99))}
+
+class _GcSpans:
+    """The garbage collector's pauses as ``host.gc`` profiler spans (keyword
+    ``gen``): one ``gc.callbacks`` hook per process, registered while a
+    :class:`StreamService` is open.  A collection runs with the interpreter
+    stopped on the thread that triggered it, so the span lands there."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._users = 0
+        self._span: Optional[TraceAnnotation] = None
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._span = TraceAnnotation("host.gc", gen=info["generation"])
+            self._span.__enter__()
+        elif self._span is not None:
+            self._span.__exit__(None, None, None)
+            self._span = None
+
+    def acquire(self) -> None:
+        with self._lock:
+            self._users += 1
+            if self._users == 1:
+                gc.callbacks.append(self)
+
+    def release(self) -> None:
+        with self._lock:
+            self._users -= 1
+            if self._users == 0 and self in gc.callbacks:
+                gc.callbacks.remove(self)
+
+
+_GC_SPANS = _GcSpans()
 
 
 _STOP = object()
@@ -524,7 +556,7 @@ class StreamService:
         self.runner = RecoveringStreamRunner(
             engine, directory, every=checkpoint_every, keep=keep,
             policy=policy, feed_method=self.adapter.feed_method,
-            blocking_saves=False)
+            blocking_saves=False, metrics=self.metrics)
         self._cursor_path = os.path.join(directory, "alerts.cursor")
         self._sidecar_path = os.path.join(directory, "service_state.json")
         self._event_seq = -1              # last assigned event sequence
@@ -542,6 +574,7 @@ class StreamService:
         self._mwe = int(w.ring) if w is not None else 0
         # current rate bound (the padded ring)
         self._resume()
+        _GC_SPANS.acquire()
         self._enc_thread = threading.Thread(
             target=self._encode_loop, name="svc-encode", daemon=True)
         self._dev_thread = threading.Thread(
@@ -808,6 +841,7 @@ class StreamService:
             self.runner.checkpoint()
         self.runner.close()
         self.dlq.close()
+        _GC_SPANS.release()
 
     # -- worker threads -------------------------------------------------
     def _encode_loop(self) -> None:
@@ -818,7 +852,8 @@ class StreamService:
                     self._enc_q.put(_STOP)
                     return
                 seq, events, n_real, t0 = item
-                args, kwargs = self.adapter.encode(events)
+                with TraceAnnotation("service.encode", chunk=seq):
+                    args, kwargs = self.adapter.encode(events)
                 self._enc_q.put((seq, args, kwargs, n_real, t0))
         except BaseException as e:   # noqa: BLE001 — surfaced to producer
             self._error = e
@@ -829,7 +864,8 @@ class StreamService:
     def _device_loop(self) -> None:
         try:
             while True:
-                item = self._enc_q.get()
+                with TraceAnnotation("service.wait_input"):
+                    item = self._enc_q.get()
                 if item is _STOP:
                     return
                 seq, args, kwargs, n_real, t0 = item
@@ -838,27 +874,29 @@ class StreamService:
                     self.metrics.skipped_chunks += 1
                     self._release(n_real)
                     continue
-                try:
-                    counts, hits, emitted = self.runner.process(
-                        *args, **kwargs)
-                except WindowOverflowError as e:
-                    if self.overflow_policy != "regrow":
-                        raise
-                    counts, hits, emitted = self._heal_overflow(
-                        e, seq, args, kwargs)
-                self._retained[seq] = (args, kwargs)
-                self._prune_retained()
-                self.metrics.chunks += 1
-                self.metrics.events_processed += n_real
-                if not emitted:
-                    self.metrics.replayed_chunks += 1
-                elif hits:
-                    self._deliver(seq, hits)
-                    self._advance_cursor(seq)
-                    self._prune_roots(seq)
-                self.metrics.chunk_latency_s.append(
-                    time.perf_counter() - t0)
-                self._release(n_real)
+                with StepTraceAnnotation("service.step", step_num=seq):
+                    try:
+                        counts, hits, emitted = self.runner.process(
+                            *args, **kwargs)
+                    except WindowOverflowError as e:
+                        if self.overflow_policy != "regrow":
+                            raise
+                        counts, hits, emitted = self._heal_overflow(
+                            e, seq, args, kwargs)
+                with TraceAnnotation("service.deliver", chunk=seq):
+                    self._retained[seq] = (args, kwargs)
+                    self._prune_retained()
+                    self.metrics.chunks += 1
+                    self.metrics.events_processed += n_real
+                    if not emitted:
+                        self.metrics.replayed_chunks += 1
+                    elif hits:
+                        self._deliver(seq, hits)
+                        self._advance_cursor(seq)
+                        self._prune_roots(seq)
+                    self.metrics.chunk_latency_s.append(
+                        time.perf_counter() - t0)
+                    self._release(n_real)
         except BaseException as e:   # noqa: BLE001 — surfaced to producer
             self._error = e
             with self._space:
@@ -896,46 +934,47 @@ class StreamService:
         on the wider ring; if it *still* overflows, the bound doubles
         again up to ``max_window_events_cap``.
         """
-        self.metrics.overflows += 1
-        lanes = [int(b) for b in np.atleast_1d(err.lanes)]
-        self.engine.quarantine(lanes)
-        target = self._mwe
-        while True:
-            if target >= self.max_window_events_cap and \
-                    _pad8(target) <= self.engine.window.ring:
-                raise StreamServiceError(
-                    f"overflow heal exhausted: chunk {seq} still overflows "
-                    f"at the max_window_events_cap="
-                    f"{self.max_window_events_cap} bound (lanes {lanes})")
-            target = min(target * self.growth_factor,
-                         self.max_window_events_cap)
-            # durable intent BEFORE any state change: a crash anywhere in
-            # the heal finds the bound (and the parked lanes) on restart
-            self._write_sidecar(target, self.engine.quarantined_lanes)
-            if self.runner.manager.latest_step() is not None:
-                self.runner.resume(max_window_events=target)
-            else:
-                self.engine.reset()
-                self.engine.regrow(target)
-                self.runner.rewind(0)
-            self.metrics.regrows += 1
-            self._mwe = int(self.engine.window.ring)
-            self.engine.clear_quarantine()
-            try:
-                for s in sorted(self._retained):
-                    if self.runner.chunk_index <= s < seq:
-                        r_args, r_kwargs = self._retained[s]
-                        counts, hits, emitted = self.runner.process(
-                            *r_args, **r_kwargs)
-                        if not emitted:
-                            self.metrics.replayed_chunks += 1
-                result = self.runner.process(*args, **kwargs)
-            except WindowOverflowError as e2:
-                self.engine.quarantine([int(b)
-                                        for b in np.atleast_1d(e2.lanes)])
-                continue
-            self._write_sidecar(self._mwe, ())
-            return result
+        with TraceAnnotation("service.heal", chunk=seq):
+            self.metrics.overflows += 1
+            lanes = [int(b) for b in np.atleast_1d(err.lanes)]
+            self.engine.quarantine(lanes)
+            target = self._mwe
+            while True:
+                if target >= self.max_window_events_cap and \
+                        _pad8(target) <= self.engine.window.ring:
+                    raise StreamServiceError(
+                        f"overflow heal exhausted: chunk {seq} still "
+                        f"overflows at the max_window_events_cap="
+                        f"{self.max_window_events_cap} bound (lanes {lanes})")
+                target = min(target * self.growth_factor,
+                             self.max_window_events_cap)
+                # durable intent BEFORE any state change: a crash anywhere in
+                # the heal finds the bound (and the parked lanes) on restart
+                self._write_sidecar(target, self.engine.quarantined_lanes)
+                if self.runner.manager.latest_step() is not None:
+                    self.runner.resume(max_window_events=target)
+                else:
+                    self.engine.reset()
+                    self.engine.regrow(target)
+                    self.runner.rewind(0)
+                self.metrics.regrows += 1
+                self._mwe = int(self.engine.window.ring)
+                self.engine.clear_quarantine()
+                try:
+                    for s in sorted(self._retained):
+                        if self.runner.chunk_index <= s < seq:
+                            r_args, r_kwargs = self._retained[s]
+                            counts, hits, emitted = self.runner.process(
+                                *r_args, **r_kwargs)
+                            if not emitted:
+                                self.metrics.replayed_chunks += 1
+                    result = self.runner.process(*args, **kwargs)
+                except WindowOverflowError as e2:
+                    self.engine.quarantine([int(b)
+                                            for b in np.atleast_1d(e2.lanes)])
+                    continue
+                self._write_sidecar(self._mwe, ())
+                return result
 
 
 __all__ = ["StreamService", "StreamServiceError", "Receipt", "TokenBucket",

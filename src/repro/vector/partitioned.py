@@ -46,6 +46,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..core.events import ComplexEvent, Event
 from ..core.partition import EMPTY_LANE, NULL_KEY_HASH, partition_key
@@ -184,81 +185,83 @@ class PartitionedStreamingEngine(StreamingVectorEngine):
         lane_ids = jnp.arange(L)
 
         # --- 1. lane assignment: scan the chunk against the key table -----
-        def assign(carry, k):
-            lane_keys, touched, lane_last = carry
-            # EMPTY_LANE is unreachable from the audited hash path; a raw
-            # feed_keyed caller passing it would match every *unowned* lane
-            # (lane_keys == k), silently sharing state with whichever
-            # partition claims that lane later — treat it as NULL instead
-            is_null = (k == jnp.uint32(NULL_KEY_HASH)) | \
-                (k == jnp.uint32(EMPTY_LANE))
-            hit = (lane_keys == k) & ~is_null                  # (L,)
-            found = hit.any()
-            empty = lane_keys == jnp.uint32(EMPTY_LANE)
-            has_empty = empty.any()
-            idx_empty = jnp.argmax(empty)
-            if self.evict == "lru":
-                # evictable: owned lanes with no events yet this chunk
-                evictable = (touched == 0) & ~empty
-                can_evict = evictable.any()
-                lru = jnp.where(evictable, lane_last, _I32_MAX)
-                idx_victim = jnp.argmin(lru)
+        with jax.named_scope("assign"):
+            def assign(carry, k):
+                lane_keys, touched, lane_last = carry
+                # EMPTY_LANE is unreachable from the audited hash path; a raw
+                # feed_keyed caller passing it would match every *unowned* lane
+                # (lane_keys == k), silently sharing state with whichever
+                # partition claims that lane later — treat it as NULL instead
+                is_null = (k == jnp.uint32(NULL_KEY_HASH)) | \
+                    (k == jnp.uint32(EMPTY_LANE))
+                hit = (lane_keys == k) & ~is_null                  # (L,)
+                found = hit.any()
+                empty = lane_keys == jnp.uint32(EMPTY_LANE)
+                has_empty = empty.any()
+                idx_empty = jnp.argmax(empty)
+                if self.evict == "lru":
+                    # evictable: owned lanes with no events yet this chunk
+                    evictable = (touched == 0) & ~empty
+                    can_evict = evictable.any()
+                    lru = jnp.where(evictable, lane_last, _I32_MAX)
+                    idx_victim = jnp.argmin(lru)
+                else:
+                    can_evict = jnp.bool_(False)
+                    idx_victim = jnp.int32(0)
+                new_lane = jnp.where(has_empty, idx_empty, idx_victim)
+                alloc_ok = has_empty | can_evict
+                lane = jnp.where(found, jnp.argmax(hit), new_lane).astype(
+                    jnp.int32)
+                ok = ~is_null & (found | alloc_ok)
+                do_alloc = ~is_null & ~found & alloc_ok
+                sel = lane_ids == lane
+                lane_keys = jnp.where(do_alloc & sel, k, lane_keys)
+                touched = touched + (sel & ok).astype(jnp.int32)
+                lane_last = jnp.where(sel & ok, chunk_idx, lane_last)
+                lane_out = jnp.where(ok, lane, jnp.int32(L))
+                return (lane_keys, touched, lane_last), (lane_out, ok, is_null)
+
+            carry0 = (state["lane_keys"], jnp.zeros((L,), jnp.int32),
+                      state["lane_last"])
+            (lane_keys, _touched, lane_last), (lanes, routed, nulls) = \
+                jax.lax.scan(assign, carry0, keys)
+
+            # lanes whose owner changed were evicted: their partition restarts
+            # from scratch if its key ever returns (fresh state, local pos 0)
+            evicted = (lane_keys != state["lane_keys"]) & \
+                (state["lane_keys"] != jnp.uint32(EMPTY_LANE))
+            if timed:
+                Cst = state["C"]
+                C = {"C": jnp.where(evicted[:, None, None], 0.0, Cst["C"]),
+                     "ts": jnp.where(evicted[:, None],
+                                     jnp.float32(wkern.TS_EMPTY), Cst["ts"]),
+                     "ovf": jnp.where(evicted, False, Cst["ovf"])}
             else:
-                can_evict = jnp.bool_(False)
-                idx_victim = jnp.int32(0)
-            new_lane = jnp.where(has_empty, idx_empty, idx_victim)
-            alloc_ok = has_empty | can_evict
-            lane = jnp.where(found, jnp.argmax(hit), new_lane).astype(
-                jnp.int32)
-            ok = ~is_null & (found | alloc_ok)
-            do_alloc = ~is_null & ~found & alloc_ok
-            sel = lane_ids == lane
-            lane_keys = jnp.where(do_alloc & sel, k, lane_keys)
-            touched = touched + (sel & ok).astype(jnp.int32)
-            lane_last = jnp.where(sel & ok, chunk_idx, lane_last)
-            lane_out = jnp.where(ok, lane, jnp.int32(L))
-            return (lane_keys, touched, lane_last), (lane_out, ok, is_null)
-
-        carry0 = (state["lane_keys"], jnp.zeros((L,), jnp.int32),
-                  state["lane_last"])
-        (lane_keys, _touched, lane_last), (lanes, routed, nulls) = \
-            jax.lax.scan(assign, carry0, keys)
-
-        # lanes whose owner changed were evicted: their partition restarts
-        # from scratch if its key ever returns (fresh state, local pos 0)
-        evicted = (lane_keys != state["lane_keys"]) & \
-            (state["lane_keys"] != jnp.uint32(EMPTY_LANE))
-        if timed:
-            Cst = state["C"]
-            C = {"C": jnp.where(evicted[:, None, None], 0.0, Cst["C"]),
-                 "ts": jnp.where(evicted[:, None],
-                                 jnp.float32(wkern.TS_EMPTY), Cst["ts"]),
-                 "ovf": jnp.where(evicted, False, Cst["ovf"])}
-        else:
-            C = jnp.where(evicted[:, None, None], 0.0, state["C"])
-        lane_pos = jnp.where(evicted, 0, state["lane_pos"])
+                C = jnp.where(evicted[:, None, None], 0.0, state["C"])
+            lane_pos = jnp.where(evicted, 0, state["lane_pos"])
 
         # --- 2. dense scatter: pack each lane's events in stream order ----
-        onehot = (lanes[:, None] == jnp.arange(L + 1)[None, :]
-                  ).astype(jnp.int32)                          # (T, L+1)
-        rank = jnp.take_along_axis(jnp.cumsum(onehot, axis=0),
-                                   lanes[:, None], axis=1)[:, 0] - 1
-        keep = routed & (rank < cap)
-        spilled = routed & ~keep                               # over capacity
-        slot = jnp.where(keep, lanes * cap + rank, L * cap)    # dummy tail
-        buf = jnp.zeros((L * cap + 1, A), attrs.dtype).at[slot].set(attrs)
-        attrs_lanes = jnp.moveaxis(
-            buf[:L * cap].reshape(L, cap, A), 0, 1)            # (cap, L, A)
-        n = (onehot[:, :L] * keep[:, None].astype(jnp.int32)).sum(0)
-        ts_lanes = None
-        if timed:
-            # per-lane timestamps ride the same routing scatter as the
-            # attributes (DESIGN.md §9); padding rows are dead steps and
-            # never consult their (zero) timestamp
-            tsbuf = jnp.zeros((L * cap + 1,), jnp.float32).at[slot].set(
-                jnp.asarray(event_ts, jnp.float32))
-            ts_lanes = jnp.moveaxis(
-                tsbuf[:L * cap].reshape(L, cap), 0, 1)         # (cap, L)
+        with jax.named_scope("scatter"):
+            onehot = (lanes[:, None] == jnp.arange(L + 1)[None, :]
+                      ).astype(jnp.int32)                          # (T, L+1)
+            rank = jnp.take_along_axis(jnp.cumsum(onehot, axis=0),
+                                       lanes[:, None], axis=1)[:, 0] - 1
+            keep = routed & (rank < cap)
+            spilled = routed & ~keep                            # over capacity
+            slot = jnp.where(keep, lanes * cap + rank, L * cap)    # dummy tail
+            buf = jnp.zeros((L * cap + 1, A), attrs.dtype).at[slot].set(attrs)
+            attrs_lanes = jnp.moveaxis(
+                buf[:L * cap].reshape(L, cap, A), 0, 1)           # (cap, L, A)
+            n = (onehot[:, :L] * keep[:, None].astype(jnp.int32)).sum(0)
+            ts_lanes = None
+            if timed:
+                # per-lane timestamps ride the same routing scatter as the
+                # attributes (DESIGN.md §9); padding rows are dead steps and
+                # never consult their (zero) timestamp
+                tsbuf = jnp.zeros((L * cap + 1,), jnp.float32).at[slot].set(
+                    jnp.asarray(event_ts, jnp.float32))
+                ts_lanes = jnp.moveaxis(
+                    tsbuf[:L * cap].reshape(L, cap), 0, 1)         # (cap, L)
 
         # --- 3. fused scan at per-lane substream positions ----------------
         with_arena = self.arena_capacity is not None
@@ -273,61 +276,64 @@ class PartitionedStreamingEngine(StreamingVectorEngine):
         matches, C = pipe[0], pipe[1]
 
         # --- 4. relabel: routed-slot counts → chunk event order -----------
-        NQ = matches.shape[-1]
-        mm = jnp.concatenate(
-            [jnp.moveaxis(matches, 0, 1).reshape(L * cap, NQ),
-             jnp.zeros((1, NQ), matches.dtype)])               # dummy row = 0
-        counts_chunk = mm[slot]                                # (T, Q)
+        with jax.named_scope("relabel"):
+            NQ = matches.shape[-1]
+            mm = jnp.concatenate(
+                [jnp.moveaxis(matches, 0, 1).reshape(L * cap, NQ),
+                 jnp.zeros((1, NQ), matches.dtype)])            # dummy row = 0
+            counts_chunk = mm[slot]                                # (T, Q)
 
-        # positions are only consumed mod W (ring slots), so the carried
-        # per-lane position wraps mod W — exact, and int32 never overflows
-        # however long a substream runs
-        new_state = {"C": C, "lane_keys": lane_keys,
-                     "lane_pos": (lane_pos + n) % self.engine.ring,
-                     "lane_last": lane_last}
-        info = {"routed": routed, "nulls": nulls, "spilled": spilled,
-                "evicted": evicted, "lane_fill": n,
-                "lanes": jnp.where(keep, lanes, jnp.int32(L))}
+            # positions are only consumed mod W (ring slots), so the carried
+            # per-lane position wraps mod W — exact, and int32 never
+            # overflows however long a substream runs
+            new_state = {"C": C, "lane_keys": lane_keys,
+                         "lane_pos": (lane_pos + n) % self.engine.ring,
+                         "lane_last": lane_last}
+            info = {"routed": routed, "nulls": nulls, "spilled": spilled,
+                    "evicted": evicted, "lane_fill": n,
+                    "lanes": jnp.where(keep, lanes, jnp.int32(L))}
 
         # --- 5. tECS arena: per-lane node stores, global position labels --
         if with_arena:
-            trace = pipe[2]                                    # (cap, L)
-            arena = dict(state["arena"])
-            # an evicted lane's partition restarts: its cells are garbage
-            arena["cell"] = jnp.where(evicted[:, None, None],
-                                      tecs_arena.NULL, arena["cell"])
-            posbuf = jnp.full((L * cap + 1,), -1, jnp.int32).at[slot].set(
-                jnp.asarray(positions, jnp.int32))
-            gpos_lanes = jnp.moveaxis(
-                posbuf[:L * cap].reshape(L, cap), 0, 1)        # (cap, L)
-            expire = (tecs_arena.window_expire_masks(
-                self.window, ts_ring0, ts_lanes, lane_pos, n)
-                if timed else None)
-            # the arena runs on LIVE dims; padded query/state tails of a
-            # fleet-style packing are dead by construction, so slicing the
-            # hit mask and consume rows to them is exact (cf. scan_chunk)
-            Qa = self._arena_tables.num_queries
-            hitsq = (matches > 0.5)[..., :Qa]
-            # CONSUME BY ANY rides the routed lanes exactly like the parent
-            # (scan_chunk): any matching query clears its own cell-table
-            # block after the step's roots are recorded (DESIGN.md D2)
-            consume = None
-            if self._consume_sq is not None:
-                consume = jnp.einsum(
-                    "tbq,qs->tbs", hitsq.astype(jnp.float32),
-                    jnp.asarray(self._consume_sq, jnp.float32)
-                    [:Qa, :self._arena_tables.num_states],
-                    precision=jax.lax.Precision.HIGHEST) > 0.5
+            with jax.named_scope("arena"):
+                trace = pipe[2]                                    # (cap, L)
+                arena = dict(state["arena"])
+                # an evicted lane's partition restarts: its cells are garbage
+                arena["cell"] = jnp.where(evicted[:, None, None],
+                                          tecs_arena.NULL, arena["cell"])
+                posbuf = jnp.full((L * cap + 1,), -1, jnp.int32).at[slot].set(
+                    jnp.asarray(positions, jnp.int32))
+                gpos_lanes = jnp.moveaxis(
+                    posbuf[:L * cap].reshape(L, cap), 0, 1)        # (cap, L)
+                expire = (tecs_arena.window_expire_masks(
+                    self.window, ts_ring0, ts_lanes, lane_pos, n)
+                    if timed else None)
+                # the arena runs on LIVE dims; padded query/state tails of a
+                # fleet-style packing are dead by construction, so slicing the
+                # hit mask and consume rows to them is exact (cf. scan_chunk)
+                Qa = self._arena_tables.num_queries
+                hitsq = (matches > 0.5)[..., :Qa]
+                # CONSUME BY ANY rides the routed lanes exactly like the parent
+                # (scan_chunk): any matching query clears its own cell-table
+                # block after the step's roots are recorded (DESIGN.md D2)
+                consume = None
+                if self._consume_sq is not None:
+                    consume = jnp.einsum(
+                        "tbq,qs->tbs", hitsq.astype(jnp.float32),
+                        jnp.asarray(self._consume_sq, jnp.float32)
+                        [:Qa, :self._arena_tables.num_states],
+                        precision=jax.lax.Precision.HIGHEST) > 0.5
             arena, roots = tecs_arena.run_arena_scan(
                 self._arena_tables, arena, trace, gpos_lanes,
                 lane_pos, n, hitsq, epsilon=self.epsilon,
                 expire=expire, consume=consume,
                 arena_impl=self.arena_impl)
-            rr = jnp.concatenate(
-                [jnp.moveaxis(roots, 0, 1).reshape(L * cap, Qa),
-                 jnp.full((1, Qa), tecs_arena.NULL, jnp.int32)])
-            new_state["arena"] = arena
-            info["roots"] = rr[slot]                           # (T, Q)
+            with jax.named_scope("relabel"):
+                rr = jnp.concatenate(
+                    [jnp.moveaxis(roots, 0, 1).reshape(L * cap, Qa),
+                     jnp.full((1, Qa), tecs_arena.NULL, jnp.int32)])
+                new_state["arena"] = arena
+                info["roots"] = rr[slot]                           # (T, Q)
         return counts_chunk, new_state, info
 
     # ------------------------------------------------------------------
@@ -440,32 +446,34 @@ class PartitionedStreamingEngine(StreamingVectorEngine):
         self._pos += T
         self._chunk_idx += 1
 
-        st = self.stats
-        st.events += T
-        st.dropped_null += int(np.asarray(info["nulls"]).sum())
-        st.spilled_capacity += int(np.asarray(info["spilled"]).sum())
-        st.routed += int(np.asarray(info["lane_fill"]).sum())
-        st.spilled_table += T - int(np.asarray(info["routed"]).sum()) \
-            - int(np.asarray(info["nulls"]).sum())
-        st.evicted_lanes += int(np.asarray(info["evicted"]).sum())
-        st.overflow_lanes = int(self.window_overflow.sum())  # latch state
-        st.quarantined_lanes = len(self._quarantined)
+        with TraceAnnotation("engine.readback"):
+            st = self.stats
+            st.events += T
+            st.dropped_null += int(np.asarray(info["nulls"]).sum())
+            st.spilled_capacity += int(np.asarray(info["spilled"]).sum())
+            st.routed += int(np.asarray(info["lane_fill"]).sum())
+            st.spilled_table += T - int(np.asarray(info["routed"]).sum()) \
+                - int(np.asarray(info["nulls"]).sum())
+            st.evicted_lanes += int(np.asarray(info["evicted"]).sum())
+            st.overflow_lanes = int(self.window_overflow.sum())  # latch
+            st.quarantined_lanes = len(self._quarantined)
 
-        counts = np.asarray(counts_f).astype(np.int64)         # (T, Q)
-        any_q = counts.sum(axis=-1)
-        if self._single_query:
-            counts = counts[:, 0]
-        if self.arena_capacity is not None:
-            roots_np = np.asarray(info["roots"])
-            lanes_np = np.asarray(info["lanes"])
-            for t in np.nonzero(any_q)[0]:
-                self._roots[int(pos_arr[t])] = (int(lanes_np[t]),
-                                                roots_np[t])
-        if positions is None:
-            hits = [base + int(t) for t in np.nonzero(any_q)[0]]
-        else:
-            hits = sorted(int(positions[t]) for t in np.nonzero(any_q)[0])
-        self._check_overflow()
+            counts = np.asarray(counts_f).astype(np.int64)     # (T, Q)
+            any_q = counts.sum(axis=-1)
+            if self._single_query:
+                counts = counts[:, 0]
+            if self.arena_capacity is not None:
+                roots_np = np.asarray(info["roots"])
+                lanes_np = np.asarray(info["lanes"])
+                for t in np.nonzero(any_q)[0]:
+                    self._roots[int(pos_arr[t])] = (int(lanes_np[t]),
+                                                    roots_np[t])
+            if positions is None:
+                hits = [base + int(t) for t in np.nonzero(any_q)[0]]
+            else:
+                hits = sorted(int(positions[t])
+                              for t in np.nonzero(any_q)[0])
+            self._check_overflow()
         return counts, hits
 
     # ------------------------------------------------------------------

@@ -45,6 +45,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..core.events import ComplexEvent, Event
 from ..core.selection import apply_strategy
@@ -808,17 +809,18 @@ class StreamingVectorEngine:
                     event_ts)
                 roots = None
         self._pos += T
-        if self._single_query:
-            counts_f = counts_f[:, :, 0]
-        counts = np.asarray(counts_f).astype(np.int64)
-        hit_dims = np.nonzero(counts.sum(axis=-1) if counts.ndim == 3
-                              else counts)
-        hits = [(t0 + int(t), int(b)) for t, b in zip(*hit_dims)]
-        if roots is not None:
-            roots_np = np.asarray(roots)
-            for p, b in hits:
-                self._roots[(p, b)] = roots_np[p - t0, b]
-        self._check_overflow()
+        with TraceAnnotation("engine.readback"):
+            if self._single_query:
+                counts_f = counts_f[:, :, 0]
+            counts = np.asarray(counts_f).astype(np.int64)
+            hit_dims = np.nonzero(counts.sum(axis=-1) if counts.ndim == 3
+                                  else counts)
+            hits = [(t0 + int(t), int(b)) for t, b in zip(*hit_dims)]
+            if roots is not None:
+                roots_np = np.asarray(roots)
+                for p, b in hits:
+                    self._roots[(p, b)] = roots_np[p - t0, b]
+            self._check_overflow()
         return counts, hits
 
     # ------------------------------------------------------------------

@@ -767,12 +767,15 @@ def run_arena_scan(atables: ArenaTables, arena: dict, trace, gpos, start,
     ``consume``: precomputed CONSUME BY ANY clear masks ((T, B, S) bool),
     or None for non-consuming queries.
     """
-    check_arena_impl(arena_impl)
-    if arena_impl == "fold":
-        return arena_scan(atables, arena, trace, gpos, start, valid, hits,
-                          epsilon=epsilon, expire=expire, consume=consume)
-    return arena_scan_block(atables, arena, trace, gpos, start, valid, hits,
-                            epsilon=epsilon, expire=expire, consume=consume)
+    with jax.named_scope("arena"):
+        check_arena_impl(arena_impl)
+        if arena_impl == "fold":
+            return arena_scan(atables, arena, trace, gpos, start, valid,
+                              hits, epsilon=epsilon, expire=expire,
+                              consume=consume)
+        return arena_scan_block(atables, arena, trace, gpos, start, valid,
+                                hits, epsilon=epsilon, expire=expire,
+                                consume=consume)
 
 
 def scan_chunk(atables: ArenaTables, arena: dict, attrs, state, *,
@@ -805,25 +808,27 @@ def scan_chunk(atables: ArenaTables, arena: dict, attrs, state, *,
         init_mask=init_mask, window=window, event_ts=event_ts,
         start_pos=start, route=route, return_trace=True,
         latest_q=latest_q, consume_sq=consume_sq)
-    T, B = trace.shape
-    gpos = jnp.broadcast_to(
-        gbase + jnp.arange(T, dtype=jnp.int32)[:, None], (T, B))
-    start_b = jnp.broadcast_to(jnp.asarray(start, jnp.int32), (B,))
-    valid_b = jnp.full((B,), T, jnp.int32)
-    expire = (window_expire_masks(window, ts_ring0, event_ts, start_b,
-                                  valid_b)
-              if window.is_time else None)
-    # the arena runs on LIVE dims (Q queries, Ŝ states); the pipeline's
-    # matches/operands may carry padded tails (fleet buckets pad query
-    # slots and packed states) — padding is dead by construction, so
-    # slicing is exact
-    hits = (matches > 0.5)[..., :atables.num_queries]
-    consume = (jnp.einsum(
-        "tbq,qs->tbs", hits.astype(jnp.float32),
-        jnp.asarray(consume_sq, jnp.float32)[:atables.num_queries,
-                                             :atables.num_states],
-        precision=jax.lax.Precision.HIGHEST) > 0.5
-        if consume_sq is not None else None)
+    # the arena half: its operands here, the builder in run_arena_scan
+    with jax.named_scope("arena"):
+        T, B = trace.shape
+        gpos = jnp.broadcast_to(
+            gbase + jnp.arange(T, dtype=jnp.int32)[:, None], (T, B))
+        start_b = jnp.broadcast_to(jnp.asarray(start, jnp.int32), (B,))
+        valid_b = jnp.full((B,), T, jnp.int32)
+        expire = (window_expire_masks(window, ts_ring0, event_ts, start_b,
+                                      valid_b)
+                  if window.is_time else None)
+        # the arena runs on LIVE dims (Q queries, Ŝ states); the pipeline's
+        # matches/operands may carry padded tails (fleet buckets pad query
+        # slots and packed states) — padding is dead by construction, so
+        # slicing is exact
+        hits = (matches > 0.5)[..., :atables.num_queries]
+        consume = (jnp.einsum(
+            "tbq,qs->tbs", hits.astype(jnp.float32),
+            jnp.asarray(consume_sq, jnp.float32)[:atables.num_queries,
+                                                 :atables.num_states],
+            precision=jax.lax.Precision.HIGHEST) > 0.5
+            if consume_sq is not None else None)
     arena, roots = run_arena_scan(
         atables, arena, trace, gpos, start_b, valid_b, hits,
         epsilon=window.epsilon, expire=expire, consume=consume,
