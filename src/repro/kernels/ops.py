@@ -83,9 +83,24 @@ class Route:
 REF_ROUTE = Route("xla", "impl='ref': the caller asked for the pure-jnp "
                          "oracle")
 
-#: the arena stage's route: the block builder has no kernel
-ARENA_ROUTE = Route("xla", "the tECS block builder is one XLA computation "
-                           "on every platform (no Pallas kernel)")
+def arena_route(tables, arena_impl: str = "block") -> Route:
+    """The arena stage's route for ``tables`` (``tecs_arena.ArenaTables``).
+
+    The builder has no kernel: it is one XLA computation on every
+    platform.  The block builder's fold reads each target's predecessor
+    cells through static slices and selects over its candidate sources
+    (:func:`repro.kernels.ref.fold_sources`); the reason names the largest
+    fan-in, so a run's set-up log states which fold it compiled.
+    """
+    if arena_impl == "fold":
+        return Route("xla", "arena_impl='fold': the per-event fold oracle, "
+                            "one XLA scan with store scatters")
+    cand = ref.fold_sources(np.asarray(tables.pred_idx))
+    fan_in = max(len(c) for per_k in cand for c in per_k)
+    return Route("xla", "the tECS block builder is one XLA computation on "
+                        "every platform (no Pallas kernel); its fold reads "
+                        "predecessor cells by static slices and selects, "
+                        f"largest fan-in {fan_in}, no state-axis gather")
 
 
 def vmem_limit(device_kind: str) -> int:

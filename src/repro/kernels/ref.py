@@ -262,14 +262,14 @@ def _scan_multi_count_ref(C0: jnp.ndarray, M_all: jnp.ndarray,
 #
 #   1. a minimal sequential recurrence over the chunk — ONLY the per-cell
 #      attribute table (node id / is-union / union children, four (B, W, S)
-#      int32 arrays) is carried, one gather + one unrolled union-gadget
-#      fold per predecessor depth per event (`arena_block_step`), emitting
-#      the cell-table *trace*; and
+#      int32 arrays) is carried, one unrolled union-gadget fold per
+#      predecessor depth per event (`arena_block_step`); each target
+#      state reads its predecessor cells through static slices and
+#      selects over the static candidate sources (`fold_sources`), never
+#      a gather along the state axis; and
 #
-#   2. fully vectorized record reconstruction over the whole chunk
-#      (`arena_records_from_trace`): the same helpers, `jax.vmap`-ed over
-#      the T axis of the trace, re-derive every allocation slot's validity
-#      and child references — no per-event work remains.
+#   2. the per-event records of every allocation slot's validity and
+#      child references, emitted by the same step in a fixed layout.
 #
 # Node ids are *virtual* while the chunk is in flight:
 #
@@ -312,6 +312,10 @@ class ArenaBlockLayout:
     * ``off_chain``   — (ε+1)·Q slots: the Fig. 5(e) right-chain, ordered by
       decreasing start age (oldest first) so chain links point backwards.
 
+    ``src_cand[k][s]`` holds the static candidate sources of target state
+    ``s`` at fold depth ``k`` (:func:`fold_sources`): the fold reads them
+    by slices and selects.
+
     The compression is purely static (from the predecessor tables), so the
     id sequence produced by the chunk-level cumsum still matches the
     per-event reference fold's allocation order exactly — the dropped
@@ -330,6 +334,7 @@ class ArenaBlockLayout:
     fin_states: Tuple[int, ...]
     ext_states: Tuple[Tuple[int, ...], ...]   # per fold depth k
     uni_states: Tuple[Tuple[int, ...], ...]   # per fold depth k (k=0: ())
+    src_cand: Tuple[Tuple[Tuple[int, ...], ...], ...]  # [k][s] → sources
     off_bottom: int
     off_ext: Tuple[int, ...]
     off_uni: Tuple[int, ...]
@@ -391,14 +396,30 @@ class ArenaBlockLayout:
         return self._region_tables()[2]
 
 
+def fold_sources(pred_idx_np) -> Tuple[Tuple[Tuple[int, ...], ...], ...]:
+    """Static candidate sources of the predecessor fold, ``[k][s]``.
+
+    The sorted set of values ``clip(pred_idx[c, s, k], 0, S−1)`` takes over
+    every class ``c``, padded tails included, so selecting among them by
+    the lane's class row equals a gather of the source cell for every
+    input, bit for bit.  The fan-in of (k, s) is the set's size.
+    """
+    pi = np.asarray(pred_idx_np)
+    pi = np.clip(pi, 0, pi.shape[1] - 1)
+    return tuple(tuple(tuple(int(v) for v in np.unique(pi[:, s, k]))
+                       for s in range(pi.shape[1]))
+                 for k in range(pi.shape[2]))
+
+
 def arena_block_layout(W: int, S: int, K: int, Q: int, epsilon: int,
-                       cap: int, init_states, finals_sq_np,
+                       cap: int, init_states, finals_sq_np, pred_idx_np,
                        pred_mark_np, pred_valid_np) -> ArenaBlockLayout:
     """Build the static slot layout for one (query tables, ring, capacity).
 
-    ``pred_mark_np``/``pred_valid_np``: the (C, S, K) predecessor tables —
-    they determine which target states can allocate at each fold depth
-    (region compression, see :class:`ArenaBlockLayout`).
+    ``pred_idx_np``/``pred_mark_np``/``pred_valid_np``: the (C, S, K)
+    predecessor tables — they determine each target's candidate sources
+    (:func:`fold_sources`) and which target states can allocate at each
+    fold depth (region compression, see :class:`ArenaBlockLayout`).
     """
     fin = tuple(int(s) for s in range(S)
                 if np.asarray(finals_sq_np)[s].any())
@@ -437,7 +458,8 @@ def arena_block_layout(W: int, S: int, K: int, Q: int, epsilon: int,
         W=W, S=S, K=K, Q=Q, epsilon=epsilon, cap=cap,
         init_states=tuple(int(s) for s in init_states), fin_states=fin,
         ext_states=ext_states, uni_states=uni_states,
-        off_bottom=off_bottom, off_ext=tuple(off_ext), off_uni=tuple(off_uni),
+        src_cand=fold_sources(pred_idx_np), off_bottom=off_bottom,
+        off_ext=tuple(off_ext), off_uni=tuple(off_uni),
         off_fs=tuple(off_fs), off_chain=off_chain, M=off)
 
 
@@ -520,14 +542,56 @@ def _state_rank(states, S: int) -> jnp.ndarray:
     return rank
 
 
-def _state_index(states) -> jnp.ndarray:
-    """(|states|,) int32 array of the state ids, iota-built (Pallas-safe)."""
-    n = len(states)
-    iota_n = jax.lax.iota(jnp.int32, n)
-    idx = jnp.zeros((n,), jnp.int32)
-    for i, s in enumerate(states):
-        idx = jnp.where(iota_n == i, s, idx)
-    return idx
+def _lane_picks() -> bool:
+    """Whether region picks take one lane slice per state (the TPU)."""
+    return jax.default_backend() == "tpu"
+
+
+def _region_cols(xs, states) -> jnp.ndarray:
+    """(B, W, S) arrays → (B, W·|states|·|xs|) record rows: per ring slot,
+    per state of ``states``, the arrays' columns in order.
+
+    On the TPU one lane slice per state, stacked along the lane axis: a
+    stack on a new minor axis of |xs| lanes is copied through a padded
+    layout there.  Elsewhere one slice per run of consecutive states: a
+    slice per state makes the CPU backend recompute each slice's producer
+    in a fusion of its own, half again the compiled builder.
+    """
+    B = xs[0].shape[0]
+    if _lane_picks():
+        cols = jnp.stack([x[:, :, s] for s in states for x in xs], axis=2)
+        return cols.reshape(B, -1)
+    runs = []
+    for s in states:
+        if runs and runs[-1][1] == s:
+            runs[-1][1] = s + 1
+        else:
+            runs.append([s, s + 1])
+    cols = [jnp.concatenate([x[:, :, a:b] for a, b in runs], axis=2)
+            for x in xs]
+    cols = cols[0] if len(xs) == 1 else jnp.stack(cols, axis=-1)
+    return cols.reshape(B, -1)
+
+
+def _fold_sources(fields, idx_k, cands):
+    """Each target's predecessor cells at one fold depth, without a gather.
+
+    fields: (B, W, S) int32 arrays; idx_k: (B, S) int32 per-lane source
+    row (clipped to the state range); cands: per target state the static
+    candidate sources (:func:`fold_sources`), which hold every value
+    ``idx_k`` can take.  A target with one candidate is a static slice;
+    more run a select chain over the candidates' slices.  Returns one
+    (B, W, S) array per field, equal to ``take_along_axis(x, idx_k, 2)``.
+    """
+    cols = [[] for _ in fields]
+    for s, cs in enumerate(cands):
+        picks = [(idx_k[:, s] == c)[:, None] for c in cs[1:]]
+        for f, x in enumerate(fields):
+            col = x[:, :, cs[0]]
+            for c, p in zip(cs[1:], picks):
+                col = jnp.where(p, x[:, :, c], col)
+            cols[f].append(col)
+    return tuple(jnp.stack(c, axis=2) for c in cols)
 
 
 def _clear_seed(cells, j, live, vbase, *, lay: ArenaBlockLayout,
@@ -586,18 +650,12 @@ def _fold_cells(cells_in, cls_t, live, vbase, *, lay: ArenaBlockLayout,
     def sel(x, states):            # (B, W, S) → (B, W·|states|), w-major
         if not states:
             return jnp.zeros((B, 0), jnp.int32)
-        idx = jnp.broadcast_to(_state_index(states)[None, None, :],
-                               (B, W, len(states)))
-        return jnp.take_along_axis(
-            jnp.broadcast_to(x, (B, W, S)), idx, axis=2).reshape(B, -1)
+        return _region_cols([jnp.broadcast_to(x, (B, W, S))], states)
 
     for k in range(lay.K):
-        idx = jnp.broadcast_to(
-            jnp.clip(pt[:, :, k, 0], 0, S - 1)[:, None, :], (B, W, S))
-        src_id = jnp.take_along_axis(cid_in, idx, axis=2)
-        src_u = jnp.take_along_axis(cisU_in, idx, axis=2)
-        src_l = jnp.take_along_axis(cleft, idx, axis=2)
-        src_r = jnp.take_along_axis(cright, idx, axis=2)
+        idx_k = jnp.clip(pt[:, :, k, 0], 0, S - 1)            # (B, S)
+        src_id, src_u, src_l, src_r = _fold_sources(
+            (cid_in, cisU_in, cleft, cright), idx_k, lay.src_cand[k])
         mk = pt[:, :, k, 1][:, None, :] > 0
         cval = ((pt[:, :, k, 2][:, None, :] > 0) & (src_id != ARENA_NULL)
                 & live[:, None, None])                     # (B, W, S)
@@ -626,14 +684,10 @@ def _fold_cells(cells_in, cls_t, live, vbase, *, lay: ArenaBlockLayout,
             acc, recs = _union_gadget(acc, contrib, cval, v0)
             v_do, l0, r0, v_both, l1_, r1_, l2_, r2_ = recs
 
-            uidx = jnp.broadcast_to(_state_index(u_states)[None, None, :],
-                                    (B, W, n_u)) if n_u else None
-
             def tri(a, b, c):      # (B, W·n·3): slots 0/1/2 per cell
-                ga, gb, gc = (jnp.take_along_axis(
-                    jnp.broadcast_to(x, (B, W, S)), uidx, axis=2)
-                    for x in (a, b, c))
-                return jnp.stack([ga, gb, gc], axis=-1).reshape(B, -1)
+                return _region_cols(
+                    [jnp.broadcast_to(x, (B, W, S)) for x in (a, b, c)],
+                    u_states)
 
             if n_u:
                 pieces.append((

@@ -349,7 +349,8 @@ class StreamingVectorEngine:
             trace=self.arena_capacity is not None, impl=self.impl,
             use_pallas=self._use_pallas, b_tile=self._b_tile)}
         if self.arena_capacity is not None:
-            routes["arena"] = ops.ARENA_ROUTE
+            routes["arena"] = ops.arena_route(self._arena_tables,
+                                              self.arena_impl)
         return routes
 
     def _make_step(self):

@@ -485,7 +485,8 @@ def _block_layout(tables: ArenaTables, W: int, epsilon: int, cap: int
         lay = kref.arena_block_layout(
             W, tables.num_states, tables.max_indegree, tables.num_queries,
             epsilon, cap, tables.init_states, np.asarray(tables.finals_sq),
-            np.asarray(tables.pred_mark), np.asarray(tables.pred_valid))
+            np.asarray(tables.pred_idx), np.asarray(tables.pred_mark),
+            np.asarray(tables.pred_valid))
         cache[key] = lay
     return lay
 
@@ -912,9 +913,10 @@ def run_enumerate(engine, streams, start_pos: int = 0,
     consume_sq = getattr(tbl, "consume_sq", None)
 
     from .engine import plan_oneshot
-    from ..kernels.ops import ARENA_ROUTE
+    from ..kernels.ops import arena_route
     route = plan_oneshot(engine, attrs.shape, trace=True)
-    engine.routes["arena"] = ARENA_ROUTE
+    engine.routes["arena"] = arena_route(
+        atables, getattr(engine, "arena_impl", "block"))
 
     def step(attrs, state, arena, start, ts):
         # one-shot: absolute positions and ring offsets coincide

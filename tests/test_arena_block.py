@@ -12,7 +12,9 @@ packed multi-query tables, the segmented scan, and lane groups under a
 record budget; the overflow latch is exercised under block allocation.
 """
 import random
+import re
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -23,9 +25,11 @@ from repro.core.events import Event
 from repro.core import compile_query
 from repro.core.partition import PartitionedEngine
 from repro.kernels import ops
+from repro.kernels import ref as kref
 from repro.vector import ArenaOverflow, StreamingVectorEngine, VectorEngine
 from repro.vector import tecs_arena
 from repro.vector.multiquery import MultiQueryEngine
+from benchmarks.cer_paper import STOCK_QUERIES
 
 QUERIES = [
     "SELECT * FROM S WHERE A ; B ; C",
@@ -35,6 +39,17 @@ QUERIES = [
     # WITHIN-declared windows are covered in tests/test_time_window.py
     "SELECT * FROM S WHERE B+",
 ]
+
+
+@pytest.fixture(autouse=True)
+def _release_compiled():
+    """Drop every compiled program after each test.  Each CPU executable
+    keeps its own memory mappings until it is freed, and this file's
+    sweeps compile thousands of them: held across the file, they reach the
+    kernel's limit on mappings per process (``vm.max_map_count``, 65,530
+    by default), and the next compile crashes the process."""
+    yield
+    jax.clear_caches()
 
 
 def make_streams(seed, B, T, alphabet="ABCX"):
@@ -272,6 +287,132 @@ def test_layout_region_compression_is_static():
     assert kind.shape == (lay.M,)
     assert kind[lay.off_bottom] == 0                      # BOTTOM
     assert (lay.d_static() >= 0).sum() == lay.E * lay.Q   # chain slots
+
+
+# ---------------------------------------------------------------------------
+# the fold's sources: static slices and selects, no state-axis gather
+# ---------------------------------------------------------------------------
+
+
+def synthetic_tables(seed, S=7, C=6, Q=2, init=(1, 2)):
+    """Random predecessor tables: several edges into most targets, so some
+    (depth, target) pairs draw on three or more sources across classes,
+    with padded tails and two init states."""
+    rng = np.random.default_rng(seed)
+    dm = np.where(rng.random((S, C)) < 0.6, rng.integers(1, S, (S, C)), 0)
+    du = np.where(rng.random((S, C)) < 0.6, rng.integers(1, S, (S, C)), 0)
+    finals_q = np.zeros((Q, S), bool)
+    finals_q[:, 1:] = rng.random((Q, S - 1)) < 0.4
+    finals_q[np.arange(Q), S - 1 - np.arange(Q)] = True
+    return tecs_arena.build_tables(dm, du, finals_q, init)
+
+
+def q3_tables():
+    ve = VectorEngine(compile_query(STOCK_QUERIES["Q3"]),
+                      max_window_events=16)
+    return ve.arena_tables()
+
+
+def run_tables_both(at, seed, W, eps, B=2, T=32, chunk=16, cap=1 << 14):
+    """Random class traces and hits through fold and block arenas on the
+    tables ``at``, ragged lanes, chunk by chunk; assert equality each
+    chunk."""
+    rng = np.random.default_rng(seed)
+    C, Q = at.pred_idx.shape[0], at.num_queries
+    cls = jnp.asarray(rng.integers(0, C, (T, B)), jnp.int32)
+    hits = rng.random((T, B, Q)) < 0.3
+    a1 = tecs_arena.init_arena(B, cap, W, at.num_states)
+    a2 = tecs_arena.init_arena(B, cap, W, at.num_states)
+    for lo in range(0, T, chunk):
+        valid = jnp.asarray([chunk, chunk - 5][:B], jnp.int32)
+        live = np.arange(chunk)[:, None] < np.asarray(valid)[None, :]
+        h = jnp.asarray(hits[lo:lo + chunk] & live[:, :, None])
+        gpos = jnp.broadcast_to(
+            lo + jnp.arange(chunk, dtype=jnp.int32)[:, None], (chunk, B))
+        start = jnp.full((B,), lo % W, jnp.int32)
+        args = (cls[lo:lo + chunk], gpos, start, valid, h)
+        a1, r1 = tecs_arena.arena_scan(at, a1, *args, epsilon=eps)
+        a2, r2 = tecs_arena.arena_scan_block(at, a2, *args, epsilon=eps)
+        assert_stores_equal(a1, a2, r1, r2, cap, f"chunk@{lo}")
+    assert not np.asarray(a1["ovf"]).any()
+    assert int(np.asarray(a1["ptr"]).sum()) > 0
+    return a1
+
+
+@pytest.mark.parametrize("case", ["synthetic0", "synthetic1", "synthetic2",
+                                  "q3", "q3_lane_picks"])
+def test_fold_sources_store_parity(monkeypatch, case):
+    """The block fold picks each target's source cells by static slices
+    and selects over the candidates the tables allow: on tables with
+    fan-in of three and more, padded tails and two init states, and on
+    Q3's own tables, the stores stay bit-identical to the per-event fold
+    oracle.  ``q3_lane_picks`` takes the TPU's region picks (one lane
+    slice per state) on this backend."""
+    if case == "q3_lane_picks":
+        monkeypatch.setattr(kref, "_lane_picks", lambda: True)
+    if case.startswith("q3"):
+        at, W, eps = q3_tables(), 16, 11
+    else:
+        at, W, eps = synthetic_tables(int(case[-1])), 8, 6
+    lay = tecs_arena._block_layout(at, W, eps, 1 << 14)
+    pi = np.clip(np.asarray(at.pred_idx), 0, at.num_states - 1)
+    for k, per_s in enumerate(lay.src_cand):
+        for s, cands in enumerate(per_s):
+            assert set(cands) == set(pi[:, s, k].tolist())
+    fan_in = max(len(c) for per_s in lay.src_cand for c in per_s)
+    if case.startswith("q3"):
+        assert fan_in == 2
+    else:
+        assert fan_in >= 3
+        assert not np.asarray(at.pred_valid).all()
+        assert len(at.init_states) == 2
+    run_tables_both(at, seed=len(case) + fan_in, W=W, eps=eps)
+
+
+def ring_wide_gathers(hlo_text, B, W):
+    """Output shapes of the gathers in compiled HLO with ≥ B·W elements."""
+    wide = []
+    for m in re.finditer(r"= \w+\[([\d,]*)\][^=]*? gather\(", hlo_text):
+        dims = [int(d) for d in m.group(1).split(",") if d]
+        if int(np.prod(dims)) >= B * W:
+            wide.append(dims)
+    return wide
+
+
+@pytest.mark.parametrize("lane_picks", [False, True])
+def test_builder_has_no_state_axis_gather(monkeypatch, lane_picks):
+    """Q3's block builder at W = 128 compiles with no ring-wide gather,
+    with either backend's region picks: only the class-row gather of the
+    predecessor tables and the hit-gated root chain (B·(ε+1)·Q elements)
+    remain.  A partitioned arena engine records the plan as its arena
+    route."""
+    monkeypatch.setattr(kref, "_lane_picks", lambda: lane_picks)
+    at, B, W, eps, T = q3_tables(), 4, 128, 63, 8
+    lay = tecs_arena._block_layout(at, W, eps, 1 << 12)
+    cells0 = tuple(jnp.full((B, W, at.num_states), v, jnp.int32)
+                   for v in (-1, 0, -1, -1))
+
+    def build(cells0, cls, hits, start, valid):
+        return kref.arena_build_ref(
+            cells0, cls, hits, start, valid, lay=lay,
+            ptab=tecs_arena._ptab(at), finals_sq=at.finals_sq)
+
+    text = jax.jit(build).lower(
+        cells0, jnp.zeros((T, B), jnp.int32),
+        jnp.zeros((T, B, at.num_queries), jnp.int32),
+        jnp.zeros((B,), jnp.int32),
+        jnp.full((B,), T, jnp.int32)).compile().as_text()
+    assert " gather(" in text                   # the class-row gather
+    assert ring_wide_gathers(text, B, W) == []
+
+    pse = VectorEngine(compile_query(STOCK_QUERIES["Q3"]),
+                       max_window_events=W).partitioned_streaming(
+        ["volume"], chunk_len=16, num_lanes=2, arena_capacity=1 << 10)
+    route = pse.routes["arena"]
+    assert route == ops.arena_route(pse._arena_tables)
+    assert route.path == "xla"
+    assert "largest fan-in 2" in route.reason
+    assert "no state-axis gather" in route.reason
 
 
 # ---------------------------------------------------------------------------

@@ -128,7 +128,8 @@ def test_streaming_arena_step_compiles(one_chip):
                                        chunk_len=CHUNK, batch=LANES,
                                        arena_capacity=8192))
     assert se.routes["scan"].path == "pallas"
-    assert se.routes["arena"] == ops.ARENA_ROUTE
+    assert se.routes["arena"] == ops.arena_route(se._arena_tables)
+    assert "no state-axis gather" in se.routes["arena"].reason
     A = len(se.encoder.attrs)
     args = (spec((CHUNK, LANES, A), jnp.float32, one_chip),
             like(se.state, one_chip), spec((), jnp.int32, one_chip),
