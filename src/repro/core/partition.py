@@ -69,6 +69,14 @@ def stable_key_hash(key: Optional[tuple]) -> int:
     return h if h < EMPTY_LANE else h - 2
 
 
+def collision_error(prev: tuple, key: tuple, h: int) -> ValueError:
+    """The device router's audit refusal: two partition keys share the
+    32-bit hash ``h``, so routing by hash would merge their substreams."""
+    return ValueError(f"partition hash collision: {prev!r} and {key!r} both "
+                      f"hash to {int(h):#x}; routing would merge their "
+                      "substreams")
+
+
 class PartitionedEngine:
     def __init__(self, make_engine: Callable[[], "object"],
                  attrs: Tuple[str, ...]):

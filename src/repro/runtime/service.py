@@ -73,7 +73,7 @@ import numpy as np
 from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from ..core.events import Event
-from ..core.partition import partition_key
+from ..core.partition import collision_error, partition_key
 from ..kernels.window import WindowOverflowError, _pad8
 from .fault_tolerance import RetryPolicy
 from .recovery import RecoveringStreamRunner, _hit_key
@@ -299,16 +299,18 @@ class _PartitionedAdapter:
             attrs, keys = eng.encoder.encode_stream_with_keys(
                 events, eng.key_attrs)
             kwargs = {}
+        if hasattr(eng, "key_values"):
+            # the key pool audits live keys only, on the device thread, and
+            # reads the operands there: they stay host arrays until the step
+            kwargs.update(event_ts=ts, key_values=eng.key_values(events, keys))
+            return (attrs, keys), kwargs
         for ev, h in zip(events, keys):
             key = partition_key(ev, eng.key_attrs)
             if key is None:
                 continue
             prev = self._hash_to_key.setdefault(int(h), key)
             if prev != key:
-                raise ValueError(
-                    f"partition hash collision: {prev!r} and {key!r} both "
-                    f"hash to {int(h):#x}; routing would merge their "
-                    "substreams")
+                raise collision_error(prev, key, h)
         return (jnp.asarray(attrs), jnp.asarray(keys)), kwargs
 
     def pad_event(self) -> Event:
@@ -939,23 +941,29 @@ class StreamService:
             lanes = [int(b) for b in np.atleast_1d(err.lanes)]
             self.engine.quarantine(lanes)
             target = self._mwe
+            # a key pool that ran out of slots grows the exhausted class
+            # (PoolOverflowError.restore_kwargs), a ring overflow the ring
+            pool = getattr(err, "restore_kwargs", None)
             while True:
-                if target >= self.max_window_events_cap and \
-                        _pad8(target) <= self.engine.window.ring:
-                    raise StreamServiceError(
-                        f"overflow heal exhausted: chunk {seq} still "
-                        f"overflows at the max_window_events_cap="
-                        f"{self.max_window_events_cap} bound (lanes {lanes})")
-                target = min(target * self.growth_factor,
-                             self.max_window_events_cap)
+                if pool is None:
+                    if target >= self.max_window_events_cap and \
+                            _pad8(target) <= self.engine.window.ring:
+                        raise StreamServiceError(
+                            f"overflow heal exhausted: chunk {seq} still "
+                            f"overflows at the max_window_events_cap="
+                            f"{self.max_window_events_cap} bound (lanes "
+                            f"{lanes})")
+                    target = min(target * self.growth_factor,
+                                 self.max_window_events_cap)
+                grow = pool or {"max_window_events": target}
                 # durable intent BEFORE any state change: a crash anywhere in
                 # the heal finds the bound (and the parked lanes) on restart
                 self._write_sidecar(target, self.engine.quarantined_lanes)
                 if self.runner.manager.latest_step() is not None:
-                    self.runner.resume(max_window_events=target)
+                    self.runner.resume(**grow)
                 else:
                     self.engine.reset()
-                    self.engine.regrow(target)
+                    self.engine.regrow(**grow)
                     self.runner.rewind(0)
                 self.metrics.regrows += 1
                 self._mwe = int(self.engine.window.ring)
@@ -972,6 +980,7 @@ class StreamService:
                 except WindowOverflowError as e2:
                     self.engine.quarantine([int(b)
                                             for b in np.atleast_1d(e2.lanes)])
+                    pool = getattr(e2, "restore_kwargs", None)
                     continue
                 self._write_sidecar(self._mwe, ())
                 return result

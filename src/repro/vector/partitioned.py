@@ -49,7 +49,8 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 from ..core.events import ComplexEvent, Event
-from ..core.partition import EMPTY_LANE, NULL_KEY_HASH, partition_key
+from ..core.partition import (EMPTY_LANE, NULL_KEY_HASH, collision_error,
+                              partition_key)
 from ..core.selection import apply_strategy
 from ..kernels import ops
 from ..kernels import window as wkern
@@ -92,6 +93,13 @@ class PartitionStats:
     #                              state, mirrors engine.quarantined_lanes —
     #                              snapshot-carried so a crash mid-heal
     #                              resumes the regrow, DESIGN.md §12)
+    slots_scanned: int = 0       # grid slots the scan ran (lanes x steps)
+    # the key pool (KeyPoolEngine) alone:
+    keys_live: int = 0           # keys holding a slot after the last chunk
+    keys_peak: int = 0           # most keys live after any chunk
+    reclaimed: int = 0           # slots freed once their window had passed
+    promoted: int = 0            # keys moved from a narrow to a wide ring
+    pool_overflow: int = 0       # keys that found no slot in their class
 
 
 class PartitionedStreamingEngine(StreamingVectorEngine):
@@ -374,10 +382,7 @@ class PartitionedStreamingEngine(StreamingVectorEngine):
                 continue
             prev = self._hash_to_key.setdefault(int(h), key)
             if prev != key:
-                raise ValueError(
-                    f"partition hash collision: {prev!r} and {key!r} both "
-                    f"hash to {int(h):#x}; routing would merge their "
-                    "substreams")
+                raise collision_error(prev, key, h)
         return self.feed_keyed(jnp.asarray(attrs), jnp.asarray(keys),
                                event_ts=None if ts is None
                                else jnp.asarray(ts), audit_ts=audit_ts)
@@ -455,6 +460,7 @@ class PartitionedStreamingEngine(StreamingVectorEngine):
             st.spilled_table += T - int(np.asarray(info["routed"]).sum()) \
                 - int(np.asarray(info["nulls"]).sum())
             st.evicted_lanes += int(np.asarray(info["evicted"]).sum())
+            st.slots_scanned += self.num_lanes * self.lane_cap
             st.overflow_lanes = int(self.window_overflow.sum())  # latch
             st.quarantined_lanes = len(self._quarantined)
 
@@ -828,3 +834,617 @@ class PartitionedStreamingEngine(StreamingVectorEngine):
         self._last_ts = None
         self._quarantined = ()
         self.stats = PartitionStats()
+
+
+# ----------------------------------------------------------------------
+# keyed state pool: thousands of live keys, rings sized per key
+# ----------------------------------------------------------------------
+class PoolOverflowError(wkern.WindowOverflowError):
+    """A key found no free slot in its ring class (``strict_overflow``).
+
+    The chunk was applied without that key's events, so its counts are
+    wrong from here on: the service heals it like a ring overflow, by
+    restoring the last checkpoint onto a pool whose exhausted class has
+    more slots (``restore_kwargs``) and replaying.  ``lanes`` is empty: no
+    slot latched, a key had none.
+    """
+
+    def __init__(self, classes: Sequence[str], restore_kwargs: dict):
+        self.classes = list(classes)
+        self.restore_kwargs = dict(restore_kwargs)
+        self.lanes = []
+        Exception.__init__(
+            self, f"key pool exhausted in class(es) {self.classes}: a key "
+            "found no free slot and its events were not applied.  Give the "
+            f"pool more slots ({self.restore_kwargs}), or drop "
+            "strict_overflow to count the loss silently (DESIGN.md §6)")
+
+
+#: ring classes of the pool, in slot-id order (narrow ids come first)
+POOL_CLASSES = ("narrow", "wide")
+#: a narrow wave runs this many events of each key, a wide wave this many
+#: keys (the rest of each class's grid follows the chunk, KeyPoolEngine)
+NARROW_STEPS = 8
+WIDE_LANES = 8
+
+
+def _wave_layout(n_u, member, uid, rank, lanes: int, steps: int):
+    """Place one ring class's events on its scan grid.
+
+    A key with ``n`` events this chunk takes ``ceil(n / steps)`` segments,
+    segment ``s`` holding its events ``s*steps ...``.  Level ``s`` (every
+    key's ``s``-th segment) fills waves of ``lanes`` segments, one key per
+    lane, and each level starts a new wave, so a key's segments run in
+    order, one wave after another.  Returns each event's wave, lane and
+    step, and the number of waves."""
+    T = n_u.shape[0]
+    levels = jnp.where(member, (n_u + steps - 1) // steps, 0)          # (U,)
+    n_lv = -(-T // steps)
+    at = levels[:, None] > jnp.arange(n_lv)[None, :]                  # (U, S)
+    per_lv = (at.sum(0) + lanes - 1) // lanes                         # waves
+    base = jnp.cumsum(per_lv) - per_lv
+    rho = jnp.cumsum(at, axis=0) - at                                 # rank
+    seg = rank // steps
+    r_e = rho[uid, jnp.minimum(seg, n_lv - 1)]
+    return (base[jnp.minimum(seg, n_lv - 1)] + r_e // lanes,
+            r_e % lanes, rank % steps, per_lv.sum())
+
+
+class KeyPoolEngine(PartitionedStreamingEngine):
+    """PARTITION BY over a pool of key slots in two ring classes.
+
+    State lives in slots, not lanes: ``slots`` narrow slots with a ring of
+    ``narrow_ring`` starts and ``wide_slots`` wide slots with the query's
+    ring (``max_window_events``).  A key takes a narrow slot when it first
+    appears and moves to a wide one, its ring copied in the step, once its
+    live window and this chunk's events would outgrow the narrow ring (or
+    its events this chunk would take more than two narrow waves).  A slot
+    is freed, in the step, once its key's newest event has left the time
+    window, so no live partial match is ever dropped (``stats.reclaimed``).
+    A key that finds no slot is a counted overflow
+    (``stats.pool_overflow``, :class:`PoolOverflowError` under
+    ``strict_overflow``), never a silent loss.
+
+    Each chunk scans only the keys it touches: per class, the keys' events
+    are laid out on a ``(steps, lanes)`` grid, gathered from the pool,
+    scanned and scattered back, with a hot key's events past one row's
+    ``steps`` in further waves of the same compiled step
+    (``stats.slots_scanned`` counts the grid slots).  The narrow grid is
+    ``chunk_len // 4`` keys by ``NARROW_STEPS`` events (a NEXmark chunk of
+    512 touches about 130 keys, most with an event or two), the wide one
+    ``WIDE_LANES`` keys by ``chunk_len // 8`` events (a few hot keys, one
+    with most of the chunk).  Time windows only: reclamation needs the
+    stream's clock (DESIGN.md §6).
+    """
+
+    _compat_keys = ("format", "engine", "query_fingerprint", "window",
+                    "chunk_len", "key_attrs", "num_states", "num_queries",
+                    "semantics", "narrow_ring")
+
+    def __init__(self, engine, key_attrs: Sequence[str], chunk_len: int, *,
+                 slots: int, wide_slots: int, narrow_ring: int = 128,
+                 impl: Optional[str] = None, strict_overflow: bool = False):
+        """``engine``: a constructed VectorEngine with a time window; its
+        ring (``max_window_events``) is the wide class's.
+
+        slots / wide_slots: keys each class holds at once.
+        narrow_ring: the narrow class's ring (a multiple of 8, at most the
+                   wide ring).
+        """
+        if not engine.window.is_time:
+            raise ValueError("the key pool reclaims a slot once its key's "
+                             "window has passed, so it needs a time window;"
+                             " use PartitionedStreamingEngine for count "
+                             "windows")
+        narrow_ring = int(narrow_ring)
+        if narrow_ring % 8 or not 8 <= narrow_ring <= engine.window.ring:
+            raise ValueError(f"narrow_ring={narrow_ring} must be a multiple "
+                             f"of 8 and at most the wide ring "
+                             f"{engine.window.ring}")
+        self.slots, self.wide_slots = int(slots), int(wide_slots)
+        self.wide_lanes = WIDE_LANES
+        self.wide_steps = max(8, int(chunk_len) // 8)
+        self._narrow_window = wkern.DeviceWindow(
+            "time", engine.window.size, engine.window.time_attr,
+            ring=narrow_ring)
+        #: hashes the last step freed, dropped from the audit before the
+        #: next chunk's (the audit holds live keys only)
+        self._reclaimed = np.zeros((0,), np.uint32)
+        super().__init__(engine, key_attrs, chunk_len,
+                         num_lanes=max(8, int(chunk_len) // 4),
+                         lane_cap=NARROW_STEPS, impl=impl, evict="none",
+                         strict_overflow=strict_overflow)
+
+    # ------------------------------------------------------------------
+    @property
+    def narrow_ring(self) -> int:
+        return self._narrow_window.ring
+
+    def _plan_routes(self) -> Dict[str, "ops.Route"]:
+        """One route per ring class: ``scan`` (narrow) and ``scan_wide``."""
+        A = len(self.encoder.attrs)
+        common = dict(A=A, S=self._m_all.shape[1], NC=self._m_all.shape[0],
+                      NQ=self._finals_q.shape[0],
+                      V=self._class_ind.shape[0], timed=True, per_lane=True,
+                      latest=self._latest_q is not None,
+                      consume=self._consume_sq is not None, impl=self.impl,
+                      use_pallas=self._use_pallas, b_tile=self._b_tile)
+        return {"scan": ops.plan_pipeline(T=self.lane_cap, B=self.num_lanes,
+                                          W=self.narrow_ring, **common),
+                "scan_wide": ops.plan_pipeline(
+                    T=self.wide_steps, B=self.wide_lanes, W=self._ring,
+                    **common)}
+
+    def _make_step(self):
+        self.routes = self._plan_routes()
+        return jax.jit(self._pool_step_impl, donate_argnums=(2,))
+
+    def _init_lane_state(self):
+        S = self._m_all.shape[1]
+        P = self.slots + self.wide_slots
+        return {
+            "C": {"narrow": wkern.init_state(self._narrow_window,
+                                             self.slots, S),
+                  "wide": wkern.init_state(self.window, self.wide_slots, S)},
+            "keys": jnp.full((P,), EMPTY_LANE, jnp.uint32),
+            "pos": jnp.zeros((P,), jnp.int32),
+            "last": jnp.full((P,), wkern.TS_EMPTY, jnp.float32),
+        }
+
+    # ------------------------------------------------------------------
+    def _scan_class(self, st, pos, sel, wave, lane, step, slot, n_waves,
+                    attrs_pad, ts_pad, counts, *, lanes, steps, window,
+                    route):
+        """Run one class's waves: gather the wave's slots, scan, scatter
+        back.  ``slot`` is each event's slot within the class."""
+        T = sel.shape[0]
+        Pc = pos.shape[0]
+        cells = steps * lanes
+
+        def body(carry):
+            w, st, pos, counts = carry
+            on = sel & (wave == w)
+            cell = jnp.where(on, step * lanes + lane, cells)
+            ev = jnp.full((cells + 1,), T, jnp.int32).at[cell].set(
+                jnp.arange(T, dtype=jnp.int32))[:cells].reshape(steps, lanes)
+            at_lane = jnp.where(on, lane, lanes)
+            lane_slot = jnp.full((lanes + 1,), Pc, jnp.int32).at[
+                at_lane].set(slot)[:lanes]
+            n = jnp.zeros((lanes + 1,), jnp.int32).at[at_lane].add(1)[:lanes]
+            g = jnp.minimum(lane_slot, Pc - 1)
+            C0 = {k: st[k][g] for k in ("C", "ts", "ovf")}
+            p0 = pos[g]
+            matches, C1 = ops.cer_pipeline(
+                attrs_pad[ev], self._specs, self._class_of, self._class_ind,
+                self._m_all, self._finals_q, C0, init_mask=self._init_mask,
+                window=window, event_ts=ts_pad[ev], start_pos=p0,
+                valid_counts=n, latest_q=self._latest_q,
+                consume_sq=self._consume_sq, route=route)
+            # a lane with no key holds slot Pc: its writes drop
+            st = {k: st[k].at[lane_slot].set(C1[k], mode="drop")
+                  for k in st}
+            pos = pos.at[lane_slot].set((p0 + n) % window.ring, mode="drop")
+            counts = jnp.where(on[:, None], matches[step, lane], counts)
+            return w + 1, st, pos, counts
+
+        _, st, pos, counts = jax.lax.while_loop(
+            lambda c: c[0] < n_waves, body, (jnp.int32(0), st, pos, counts))
+        return st, pos, counts
+
+    def _pool_step_impl(self, attrs: jnp.ndarray, keys: jnp.ndarray, state,
+                        event_ts: jnp.ndarray):
+        self._trace_count += 1  # runs only while tracing (i.e. compiling)
+        T, A = attrs.shape
+        Pn, Pw = self.slots, self.wide_slots
+        P = Pn + Pw
+        Wn, Ww = self.narrow_ring, self.window.ring
+        # wide slots a chunk may take: every free one (a chunk's keys may
+        # all outgrow their narrow rings at once)
+        K = min(Pw, T)
+        EMPTY = jnp.uint32(EMPTY_LANE)
+        NULL = jnp.uint32(NULL_KEY_HASH)
+        ts = jnp.asarray(event_ts, jnp.float32)
+        idx = jnp.arange(T, dtype=jnp.int32)
+
+        with jax.named_scope("classify"):
+            valid = (keys != NULL) & (keys != EMPTY)
+            thr = jnp.where(valid.any(),
+                            jnp.min(jnp.where(valid, ts, jnp.inf))
+                            - jnp.float32(self.window.size), -jnp.inf)
+            # the chunk's distinct keys: a stable sort keeps each key's
+            # events in stream order, so rank is the event's place in it
+            order = jnp.argsort(jnp.where(valid, keys, NULL), stable=True)
+            sk = jnp.where(valid, keys, NULL)[order]
+            first = jnp.concatenate([jnp.ones((1,), bool), sk[1:] != sk[:-1]])
+            u_sorted = (jnp.cumsum(first) - 1).astype(jnp.int32)
+            run0 = jax.lax.cummax(jnp.where(first, idx, 0))
+            uid = jnp.zeros((T,), jnp.int32).at[order].set(u_sorted)
+            rank = jnp.zeros((T,), jnp.int32).at[order].set(idx - run0)
+            ukey = jnp.full((T,), NULL).at[u_sorted].set(sk)
+            ukey_ok = ukey != NULL
+            n_u = jnp.zeros((T,), jnp.int32).at[uid].add(
+                valid.astype(jnp.int32))
+
+        with jax.named_scope("reclaim"):
+            # a slot whose key's newest event left the window before this
+            # chunk's first event holds no live start: free it
+            dead = (state["keys"] != EMPTY) & (state["last"] < thr)
+            tk = jnp.where(dead, EMPTY, state["keys"])
+
+        with jax.named_scope("assign"):
+            s_keys, s_slot = jax.lax.sort(
+                (tk, jnp.arange(P, dtype=jnp.int32)), num_keys=1)
+            j = jnp.minimum(jnp.searchsorted(s_keys, ukey), P - 1)
+            found = ukey_ok & (s_keys[j] == ukey)
+            slot_u = jnp.where(found, s_slot[j], -1)
+            narrow_u = found & (slot_u < Pn)
+            nar = state["C"]["narrow"]
+            live0 = jnp.sum(nar["ts"][jnp.where(narrow_u, slot_u, 0)] >= thr,
+                            axis=1)
+            wide_u = found & ~narrow_u
+            to_wide = ukey_ok & ~wide_u & (
+                (jnp.where(narrow_u, live0, 0) + n_u > Wn)
+                | (n_u > 2 * self.lane_cap))
+            want_n = ukey_ok & ~found & ~to_wide
+            free_n = tk[:Pn] == EMPTY
+            free_w = tk[Pn:] == EMPTY
+            r_n = jnp.cumsum(want_n) - 1
+            got_n = want_n & (r_n < free_n.sum())
+            slot_n = jnp.nonzero(free_n, size=min(T, Pn), fill_value=Pn)[0][
+                jnp.clip(r_n, 0, min(T, Pn) - 1)]
+            r_w = jnp.cumsum(to_wide) - 1
+            got_w = to_wide & (r_w < jnp.minimum(free_w.sum(), K))
+            slot_w = Pn + jnp.nonzero(free_w, size=K, fill_value=Pw)[0][
+                jnp.clip(r_w, 0, K - 1)]
+            promoted = got_w & narrow_u
+            # a key refused a wide slot keeps its narrow one (and latches
+            # its ring if it overflows); a new key refused any slot is lost
+            slot_f = jnp.where(got_n, slot_n, jnp.where(got_w, slot_w,
+                                                        slot_u))
+            tk = tk.at[jnp.where(got_n | got_w, slot_f, P)].set(
+                ukey, mode="drop")
+            tk = tk.at[jnp.where(promoted, slot_u, P)].set(EMPTY,
+                                                           mode="drop")
+
+        with jax.named_scope("promote"):
+            wide = dict(state["C"]["wide"])
+            nar = dict(nar)
+            pos = state["pos"]
+            last = state["last"]
+            # fresh narrow slots start empty
+            new_n = jnp.nonzero(got_n, size=min(T, Pn), fill_value=T)[0]
+            dst_n = jnp.where(new_n < T, slot_f[jnp.minimum(new_n, T - 1)],
+                              Pn)
+            nar["C"] = nar["C"].at[dst_n].set(0.0, mode="drop")
+            nar["ts"] = nar["ts"].at[dst_n].set(wkern.TS_EMPTY, mode="drop")
+            nar["ovf"] = nar["ovf"].at[dst_n].set(False, mode="drop")
+            pos = pos.at[jnp.where(dst_n < Pn, dst_n, P)].set(0, mode="drop")
+            # wide slots: a promoted key's narrow ring moves start j from
+            # slot j mod Wn to j mod Ww in the virtual frame p + Wn (the
+            # ring-regrow remap, DESIGN.md §12); a new key starts empty
+            new_w = jnp.nonzero(got_w, size=K, fill_value=T)[0]
+            ok_w = new_w < T
+            u_w = jnp.minimum(new_w, T - 1)
+            is_p = ok_w & promoted[u_w]
+            src = jnp.where(is_p, slot_u[u_w], 0)
+            frame = pos[src] + Wn                                       # (K,)
+            k = jnp.arange(Wn)
+            start = frame[:, None] - 1 - ((frame[:, None] - 1 - k) % Wn)
+            to = start % Ww                                            # (K, Wn)
+            rows = jnp.arange(K)[:, None]
+            S = nar["C"].shape[-1]
+            wC = jnp.zeros((K, Ww, S), jnp.float32).at[rows, to].set(
+                nar["C"][src])
+            wts = jnp.full((K, Ww), wkern.TS_EMPTY, jnp.float32).at[
+                rows, to].set(nar["ts"][src])
+            keep = is_p[:, None]
+            dst_w = jnp.where(ok_w, slot_f[u_w] - Pn, Pw)
+            wide["C"] = wide["C"].at[dst_w].set(
+                jnp.where(keep[:, :, None], wC, 0.0), mode="drop")
+            wide["ts"] = wide["ts"].at[dst_w].set(
+                jnp.where(keep, wts, wkern.TS_EMPTY), mode="drop")
+            wide["ovf"] = wide["ovf"].at[dst_w].set(
+                is_p & nar["ovf"][src], mode="drop")
+            gdst = jnp.where(ok_w, slot_f[u_w], P)
+            pos = pos.at[gdst].set(jnp.where(is_p, frame % Ww, 0),
+                                   mode="drop")
+            last = last.at[gdst].set(
+                jnp.where(is_p, last[src], wkern.TS_EMPTY), mode="drop")
+
+        with jax.named_scope("scatter"):
+            slot_e = jnp.where(valid, slot_f[uid], -1)
+            routed = slot_e >= 0
+            is_w = slot_e >= Pn
+            attrs_pad = jnp.concatenate([attrs, jnp.zeros((1, A), attrs.dtype)])
+            ts_pad = jnp.concatenate([jnp.where(valid, ts, 0.0),
+                                      jnp.zeros((1,), jnp.float32)])
+            on_n = ukey_ok & (slot_f >= 0) & (slot_f < Pn)
+            on_w = ukey_ok & (slot_f >= Pn)
+            lay_n = _wave_layout(n_u, on_n, uid, rank, self.num_lanes,
+                                 self.lane_cap)
+            lay_w = _wave_layout(n_u, on_w, uid, rank, self.wide_lanes,
+                                 self.wide_steps)
+            last = last.at[jnp.where(routed, slot_e, P)].max(ts, mode="drop")
+
+        NQ = self._finals_q.shape[0]
+        counts = jnp.zeros((T, NQ), jnp.float32)
+        nar, pos_n, counts = self._scan_class(
+            nar, pos[:Pn], routed & ~is_w, *lay_n[:3],
+            jnp.minimum(slot_e, Pn - 1), lay_n[3], attrs_pad, ts_pad,
+            counts, lanes=self.num_lanes, steps=self.lane_cap,
+            window=self._narrow_window, route=self.routes["scan"])
+        wide, pos_w, counts = self._scan_class(
+            wide, pos[Pn:], is_w, *lay_w[:3], jnp.maximum(slot_e - Pn, 0),
+            lay_w[3], attrs_pad, ts_pad, counts, lanes=self.wide_lanes,
+            steps=self.wide_steps, window=self.window,
+            route=self.routes["scan_wide"])
+
+        with jax.named_scope("relabel"):
+            new_state = {"C": {"narrow": nar, "wide": wide}, "keys": tk,
+                         "pos": jnp.concatenate([pos_n, pos_w]),
+                         "last": last}
+            n_slots = (lay_n[3] * (self.num_lanes * self.lane_cap)
+                       + lay_w[3] * (self.wide_lanes * self.wide_steps))
+            # the counters in one readback: routed, NULL, promoted, lost
+            # (narrow, wide), grid slots, live keys, latched slots
+            info = jnp.stack([
+                routed.sum(), (~valid).sum(), promoted.sum(),
+                (want_n & ~got_n).sum(), (to_wide & ~got_w).sum(),
+                n_slots, (tk != EMPTY).sum(),
+                nar["ovf"].sum() + wide["ovf"].sum()]).astype(jnp.int32)
+        return counts, new_state, info, jnp.where(dead, state["keys"], EMPTY)
+
+    # ------------------------------------------------------------------
+    def key_values(self, events: Sequence[Event], keys) -> Dict[int, tuple]:
+        """The chunk's ``{hash: key}``, raising if two keys of the chunk
+        share a hash (the rest of the audit runs in :meth:`feed_keyed`)."""
+        out: Dict[int, tuple] = {}
+        for ev, h in zip(events, keys):
+            key = partition_key(ev, self.key_attrs)
+            if key is not None and out.setdefault(int(h), key) != key:
+                raise collision_error(out[int(h)], key, h)
+        return out
+
+    def _audit(self, key_values: Dict[int, tuple]) -> None:
+        """Collision audit among live keys: the table drops a key when its
+        slot is reclaimed, so it (and the manifest) holds live keys only."""
+        table = self._hash_to_key
+        for h in self._reclaimed.tolist():
+            table.pop(h, None)
+        self._reclaimed = self._reclaimed[:0]
+        for h, key in key_values.items():
+            prev = table.setdefault(h, key)
+            if prev != key:
+                raise collision_error(prev, key, h)
+
+    def feed(self, events: Sequence[Event]) -> Tuple[np.ndarray, List[int]]:
+        """Feed one chunk of ``chunk_len`` raw interleaved events; see
+        :meth:`PartitionedStreamingEngine.feed`.  Events need the query's
+        ``time_attr`` or a timestamp: the pool reclaims by the stream's
+        clock, so there is no per-key arrival-order fallback."""
+        if len(events) != self.chunk_len:
+            raise ValueError(f"partitioned chunk must have chunk_len="
+                             f"{self.chunk_len} events; got {len(events)}")
+        attrs, keys, ts = self.encoder.encode_stream_keyed_ts(
+            events, self.key_attrs, self.window.time_attr)
+        return self.feed_keyed(attrs, keys, event_ts=ts,
+                               key_values=self.key_values(events, keys))
+
+    def feed_keyed(self, attrs: jnp.ndarray, keys: jnp.ndarray,
+                   positions: Optional[np.ndarray] = None, event_ts=None,
+                   audit_ts: bool = True,
+                   key_values: Optional[Dict[int, tuple]] = None
+                   ) -> Tuple[np.ndarray, List[int]]:
+        """Device-tensor entry point, as the parent's.  ``key_values``
+        (the chunk's ``{hash: key}``, :meth:`key_values`) runs the
+        collision audit; without it the caller owns that risk."""
+        T = attrs.shape[0]
+        if T != self.chunk_len or keys.shape != (T,):
+            raise ValueError(f"expected attrs (chunk_len={self.chunk_len}, "
+                             f"A) and keys ({self.chunk_len},); got "
+                             f"{attrs.shape} / {keys.shape}")
+        if positions is not None or not audit_ts or event_ts is None:
+            raise ValueError("the key pool takes contiguous chunks with the "
+                             "stream's own clock (event_ts, monotone): no "
+                             "routed positions, no per-key fallback clock")
+        with TraceAnnotation("partition.keys"):
+            ts_np = np.asarray(event_ts, np.float32)
+            keys_np = np.asarray(keys, np.uint32)
+            rows = (keys_np != np.uint32(NULL_KEY_HASH)) & \
+                (keys_np != np.uint32(EMPTY_LANE))
+            if rows.any():
+                self._last_ts = wkern.audit_monotone_ts(ts_np[rows],
+                                                        self._last_ts)
+            if key_values is not None:
+                self._audit(key_values)
+        base = self._pos
+        with _quiet_donation():
+            counts_f, self._state, info, rec = self._step(
+                attrs, keys, self._state, event_ts)
+        self._pos += T
+        self._chunk_idx += 1
+        with TraceAnnotation("engine.readback"):
+            routed, nulls, promoted, lost_n, lost_w, slots, live, ovf = (
+                int(x) for x in np.asarray(info))
+            rec = np.asarray(rec)
+            st = self.stats
+            st.reclaimed += int((rec != np.uint32(EMPTY_LANE)).sum())
+            # a reclaimed key back in this chunk holds a fresh slot: it
+            # stays in the audit
+            self._reclaimed = np.asarray(
+                [h for h in rec[rec != np.uint32(EMPTY_LANE)].tolist()
+                 if key_values is None or h not in key_values], np.uint32)
+            st.events += T
+            st.routed += routed
+            st.dropped_null += nulls
+            st.spilled_table += T - routed - nulls
+            st.promoted += promoted
+            st.pool_overflow += lost_n + lost_w
+            st.slots_scanned += slots
+            st.keys_live = live
+            st.keys_peak = max(st.keys_peak, live)
+            st.overflow_lanes = ovf
+            st.quarantined_lanes = len(self._quarantined)
+            counts = np.asarray(counts_f).astype(np.int64)
+            any_q = counts.sum(axis=-1)
+            if self._single_query:
+                counts = counts[:, 0]
+            hits = [base + int(t) for t in np.nonzero(any_q)[0]]
+            if self.strict_overflow and (lost_n or lost_w):
+                grow = {}
+                if lost_n:
+                    grow["slots"] = 2 * self.slots
+                if lost_w:
+                    grow["wide_slots"] = 2 * self.wide_slots
+                raise PoolOverflowError(sorted(grow), grow)
+            if ovf:
+                self._check_overflow()
+        return counts, hits
+
+    # ------------------------------------------------------------------
+    @property
+    def window_overflow(self) -> np.ndarray:
+        """Per-slot latched rate-bound flags, narrow slots first."""
+        return np.concatenate([np.asarray(self._state["C"][c]["ovf"])
+                               for c in POOL_CLASSES])
+
+    @property
+    def num_active_lanes(self) -> int:
+        """Keys that hold a slot."""
+        return int((np.asarray(self._state["keys"])
+                    != np.uint32(EMPTY_LANE)).sum())
+
+    def evict_idle(self, min_idle_chunks: int = 1) -> int:
+        raise TypeError("the key pool frees a slot in the step, once its "
+                        "key's window has passed; there is nothing to evict")
+
+    def manifest(self) -> dict:
+        self._audit({})
+        m = StreamingVectorEngine.manifest(self)
+        m.update({"narrow_ring": self.narrow_ring,
+                  "pool": {"slots": self.slots,
+                           "wide_slots": self.wide_slots},
+                  "key_attrs": list(self.key_attrs),
+                  "chunk_idx": int(self._chunk_idx),
+                  "stats": asdict(self.stats),
+                  "hash_to_key": _encode_hash_to_key(self._hash_to_key)})
+        return m
+
+    def snapshot(self) -> dict:
+        """Host snapshot of the live pool: per class, the slots that hold a
+        key, and of their rings only the starts still live for any event
+        to come (older ones would expire at the next event anyway), so a
+        checkpoint's bytes follow live state, not the pool's size."""
+        st = {}
+        _flatten_state("state", self._state, st)
+        keys = st["state/keys"]
+        arrays: Dict[str, np.ndarray] = {}
+        thr = (np.float32(self._last_ts[0]) - np.float32(self.window.size)
+               if self._last_ts is not None else np.float32(-np.inf))
+        lo = 0
+        for c, n in zip(POOL_CLASSES, (self.slots, self.wide_slots)):
+            live = np.nonzero(keys[lo:lo + n] != np.uint32(EMPTY_LANE))[0]
+            ts = st[f"state/C/{c}/ts"][live]
+            row, ring = np.nonzero(ts >= thr)
+            arrays.update({
+                f"pool/{c}/slot": live.astype(np.int32),
+                f"pool/{c}/key": keys[lo + live],
+                f"pool/{c}/pos": st["state/pos"][lo + live],
+                f"pool/{c}/last": st["state/last"][lo + live],
+                f"pool/{c}/ovf": st[f"state/C/{c}/ovf"][live],
+                f"pool/{c}/row": row.astype(np.int32),
+                f"pool/{c}/ring": ring.astype(np.int32),
+                f"pool/{c}/ts": ts[row, ring],
+                f"pool/{c}/C": st[f"state/C/{c}/C"][live][row, ring]})
+            lo += n
+        if self._last_ts is not None:
+            arrays["last_ts"] = np.asarray(self._last_ts, np.float32)
+        return {"arrays": arrays, "meta": self.manifest()}
+
+    def restore(self, snapshot: dict, *,
+                max_window_events: Optional[int] = None,
+                slots: Optional[int] = None,
+                wide_slots: Optional[int] = None) -> None:
+        """Load a :meth:`snapshot`, onto a larger pool (``slots``,
+        ``wide_slots``) or a wider wide ring (``max_window_events``) where
+        asked: the overflow heal grows only the class that overflowed.
+        A smaller pool than the snapshot's live keys is refused."""
+        meta, arrays = snapshot["meta"], snapshot["arrays"]
+        snap_ring = int((meta.get("window") or {}).get("ring",
+                                                       self.window.ring))
+        new_w = (self.window.regrow(max_window_events)
+                 if max_window_events is not None else self.window)
+        skip: Tuple[str, ...] = ()
+        if snap_ring != new_w.ring:
+            self._check_window_elastic(meta, target_ring=new_w.ring)
+            skip = ("window",)
+        self._check_manifest(meta, skip=skip)
+        # never smaller than the pool the snapshot was taken from (a heal
+        # may have grown it before a restart)
+        had = meta.get("pool") or {}
+        size = {"narrow": max(int(slots or self.slots),
+                              int(had.get("slots", 0))),
+                "wide": max(int(wide_slots or self.wide_slots),
+                            int(had.get("wide_slots", 0)))}
+        for c in POOL_CLASSES:
+            have = arrays[f"pool/{c}/slot"]
+            if len(have) and int(have.max()) >= size[c]:
+                raise ValueError(f"snapshot holds {c} slot {int(have.max())}"
+                                 f"; the pool has {size[c]}")
+        if (size["narrow"], size["wide"]) != (self.slots, self.wide_slots) \
+                or new_w.ring != self.window.ring:
+            self.slots, self.wide_slots = size["narrow"], size["wide"]
+            if new_w.ring != self.window.ring:
+                self._apply_ring(new_w)
+            else:
+                self._trace_count = 0
+                self._step = self._make_step()
+        st = {}
+        _flatten_state("state", self._init_lane_state(), st)
+        st = {k: np.array(v) for k, v in st.items()}
+        rings = {"narrow": (self.narrow_ring, self.narrow_ring),
+                 "wide": (snap_ring, self.window.ring)}
+        for c in POOL_CLASSES:
+            g = arrays[f"pool/{c}/slot"].astype(np.int64)
+            off = 0 if c == "narrow" else self.slots
+            pos = arrays[f"pool/{c}/pos"].astype(np.int64)
+            row = arrays[f"pool/{c}/row"]
+            ring = arrays[f"pool/{c}/ring"].astype(np.int64)
+            w0, w1 = rings[c]
+            if w1 != w0:
+                # the regrow remap in the virtual frame pos + w0 (§12)
+                frame = pos + w0
+                f = frame[row]
+                ring = (f - 1 - ((f - 1 - ring) % w0)) % w1
+                pos = frame % w1
+            st["state/keys"][off + g] = arrays[f"pool/{c}/key"]
+            st["state/pos"][off + g] = pos
+            st["state/last"][off + g] = arrays[f"pool/{c}/last"]
+            st[f"state/C/{c}/ovf"][g] = arrays[f"pool/{c}/ovf"]
+            st[f"state/C/{c}/ts"][g[row], ring] = arrays[f"pool/{c}/ts"]
+            st[f"state/C/{c}/C"][g[row], ring] = arrays[f"pool/{c}/C"]
+        self._state = _restore_like("state", self._init_lane_state(), st)
+        self._pos = int(meta["pos"])
+        self._chunk_idx = int(meta["chunk_idx"])
+        self._last_ts = (np.asarray(arrays["last_ts"], np.float32)
+                         if "last_ts" in arrays else None)
+        self.stats = PartitionStats(**meta.get("stats", {}))
+        htk = meta.get("hash_to_key")
+        self._hash_to_key = ({int(h): tuple(k) for h, k in htk}
+                             if htk else {})
+        self._reclaimed = self._reclaimed[:0]
+        self._quarantined = tuple(
+            int(b) for b in meta.get("quarantined_lanes", ()))
+        self.stats.quarantined_lanes = len(self._quarantined)
+
+    def regrow(self, max_window_events: Optional[int] = None, *,
+               slots: Optional[int] = None,
+               wide_slots: Optional[int] = None) -> None:
+        """Grow the wide ring and/or a class's slots in place (snapshot →
+        :meth:`restore`); the next feed compiles once for the new shapes."""
+        self.restore(self.snapshot(), max_window_events=max_window_events,
+                     slots=slots, wide_slots=wide_slots)
+
+    def reset(self) -> None:
+        super().reset()
+        self._reclaimed = self._reclaimed[:0]

@@ -9,6 +9,7 @@ the process that runs these tests may load the TPU compiler.
 """
 import dataclasses
 import importlib.util
+import json
 import os
 
 import jax
@@ -173,3 +174,29 @@ def test_served_widths_cover_the_stream():
     assert cs.ring_for(events, "volume", win.time_attr, win.size) \
         <= cfg["ring"]
     assert cs.lane_fill(events, "volume", cfg["chunk"]) <= cfg["lane_cap"]
+
+
+def test_key_pool_step_compiles(one_chip):
+    """The key pool's step at the nexmark_newuser configuration's widths:
+    4,096 narrow slots of 128 starts on the fused kernel, 96 wide slots of
+    4,096 starts on XLA, the waves in while loops; within HBM."""
+    from repro.vector import KeyPoolEngine
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks", "chip", "configs",
+        "nexmark_newuser.json")
+    with open(path) as f:
+        cfg = json.load(f)
+    e = cfg["engine"]
+    eng = on_chip(KeyPoolEngine(
+        VectorEngine(compile_query(cfg["query"]), max_window_events=e["ring"]),
+        tuple(e["partition_by"]), e["chunk_len"], slots=e["slots"],
+        wide_slots=e["wide_slots"], narrow_ring=e["narrow_ring"],
+        strict_overflow=True))
+    assert eng.routes["scan"].path == "pallas"
+    assert eng.routes["scan_wide"].path == "xla"
+    T, A = eng.chunk_len, len(eng.encoder.attrs)
+    args = (spec((T, A), jnp.float32, one_chip),
+            spec((T,), jnp.uint32, one_chip), like(eng.state, one_chip),
+            spec((T,), jnp.float32, one_chip))
+    text = compiled_fits(eng._step.lower(*args).compile())
+    assert "tpu_custom_call" in text
